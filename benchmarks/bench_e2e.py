@@ -27,6 +27,7 @@ import numpy as np
 
 from repro.core import SparseTensor, build_network_plan
 from repro.data import scenes
+from repro.kernels import ops
 from repro.models import pointcloud as pc
 from repro.obs import MetricsRegistry
 from repro.serve import compile_network
@@ -56,7 +57,7 @@ def run(smoke: bool = False):
     for engine in ["zdelta", "zdelta_pallas"]:
         # interpreter off-TPU: keep the pallas engine to the small scene
         layout, clouds = (small if engine != "zdelta"
-                          and jax.default_backend() != "tpu" else full)
+                          and not ops.on_tpu() else full)
         session = compile_network(net, layout, batch=B, engine=engine)
         st1 = SparseTensor.from_point_clouds(clouds[:1], session.layout)
         st_b = SparseTensor.from_point_clouds(clouds, session.layout)

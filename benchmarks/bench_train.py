@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.data import scenes
+from repro.kernels import ops
 from repro.models import pointcloud as pc
 from repro.obs import MetricsRegistry
 from repro.serve import compile_network
@@ -81,7 +82,7 @@ def run(smoke: bool = False):
     rows, engines_rec = [], {}
     reg = MetricsRegistry()   # per-repeat latencies → percentile export
     engines = ["zdelta", "zdelta_pallas"]
-    if not smoke and jax.default_backend() != "tpu":
+    if not smoke and not ops.on_tpu():
         engines = ["zdelta"]   # interpreter-priced pallas only at smoke size
 
     for engine in engines:

@@ -16,6 +16,7 @@ from repro.core import (offset_grid, pack_offsets, simple_bsearch,
                         symmetry_anchor_count, zdelta_offsets, zdelta_search,
                         zdelta_search_symmetric)
 from repro.core import hashmap
+from repro.kernels import ops
 from repro.kernels.zdelta_window import zdelta_superwindow_search
 from .common import emit, prep, scene_set, timeit, us
 
@@ -64,7 +65,7 @@ def run(K: int = 3):
         if si < PALLAS_SCENES:
             cap = ((cs.capacity + 127) // 128) * 128   # full 128-row tiles
             csp, _ = prep(sc, capacity=cap)
-            interpret = jax.default_backend() != "tpu"
+            interpret = not ops.on_tpu()
             sw = jax.jit(lambda c: zdelta_superwindow_search(
                 c, c, anchors, zstep, K=K, W=min(4096, cap),
                 interpret=interpret)[0])

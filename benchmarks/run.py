@@ -14,6 +14,8 @@ import argparse
 import sys
 import traceback
 
+from repro.compile_cache import enable_compile_cache
+
 from . import (bench_dataflow, bench_e2e, bench_indexing, fig2_breakdown,
                fig3b_density, fig7_end2end, fig8_layerwise, fig9_dataflow,
                fig10_mapping, fig11_ablation, fig12_networkwide)
@@ -40,6 +42,7 @@ def main() -> None:
                     help="primary dataflow backend; implies the 'dataflow' "
                          "bench when no figs are listed")
     args = ap.parse_args()
+    enable_compile_cache()
 
     which = args.figs or (["dataflow"] if args.backend else list(ALL))
     print("name,us_per_call,derived")

@@ -375,7 +375,7 @@ def _ws_primal(cfg, features, m, weights):
     use_pallas, _i = kops.resolve_backend(backend)
     if use_pallas:
         return kops.spconv_ws_fused(features, m, weights, capacity=capacity,
-                                    impl="pallas", bc=bm, bn=bn)
+                                    impl="pallas", bm=bm, bn=bn)
     return ws_xla(features, m, weights, capacity=capacity)
 
 

@@ -115,9 +115,16 @@ def _pallas_map(inputs: CoordSet, outputs: CoordSet, anchors, zstep,
     always runs full 128-row tiles regardless of the caller's capacity
     (PAD rows resolve to −1 and never count as overflow); the map is
     sliced back to the caller's capacity."""
+    from repro.kernels import ops
     from repro.kernels.zdelta_window import (zdelta_superwindow_search,
                                              zdelta_window_search)
 
+    if ops.on_tpu():
+        raise NotImplementedError(
+            "the z-delta Pallas search kernels (engine 'zdelta_pallas' / "
+            "'zdelta_pallas_window') do not compile for TPU yet: Mosaic "
+            "refuses their (1, bm) block shapes and in-VMEM vector gathers "
+            "(ROADMAP). Use engine='zdelta', the XLA search.")
     mcap = outputs.packed.shape[0]
     bm = PLAN_BM
     mcap2 = ((mcap + bm - 1) // bm) * bm
@@ -129,16 +136,15 @@ def _pallas_map(inputs: CoordSet, outputs: CoordSet, anchors, zstep,
                         outputs.packed.dtype).at[:mcap].set(outputs.packed)
         out_padded = CoordSet(packed=outp, count=outputs.count)
     n = inputs.packed.shape[0]
-    interpret = jax.default_backend() != "tpu"
     if superwindow:
         W = min(W or max(16 * bm, 2048), n)
         m_p, ovf = zdelta_superwindow_search(inputs, out_padded, anchors,
                                              zstep, K=K, W=W, bm=bm,
-                                             interpret=interpret)
+                                             interpret=True)
     else:
         W = min(W or max(4 * bm, 512), n)
         m_p, ovf = zdelta_window_search(inputs, out_padded, anchors, zstep,
-                                        K=K, W=W, bm=bm, interpret=interpret)
+                                        K=K, W=W, bm=bm, interpret=True)
     m_p = m_p[:mcap]
 
     def patched():
@@ -213,7 +219,8 @@ def build_network_plan(
     ``engine`` selects the mapping algorithm (zdelta = Spira; bsearch and
     hash are the paper's baselines) so benchmarks compare within one code
     path. ``zdelta_pallas`` runs the superwindow Pallas kernel (one DMA per
-    output tile; interpret-mode off TPU) per layer, with a per-tile fallback
+    output tile; CPU interpreter only — it raises on TPU, whose compiler
+    refuses it) per layer, with a per-tile fallback
     to the XLA search for window-overflow cells — maps are identical to
     ``zdelta`` by construction; ``zdelta_pallas_window`` keeps PR 1's
     per-group-window kernel for comparison. The per-layer window W comes

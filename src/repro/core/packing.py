@@ -27,7 +27,7 @@ commutes with the bias. Default guard = 16 (covers K<=9 at s_p<=8 and strides
 up to 16).
 
 64-bit packing uses jnp.int64 and therefore requires x64 (wrap call sites in
-``jax.experimental.enable_x64()``); the 32-bit path is the default everywhere,
+``jax.enable_x64(True)``); the 32-bit path is the default everywhere,
 matching the paper's finding that 32-bit suffices for real workloads.
 """
 from __future__ import annotations

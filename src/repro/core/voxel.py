@@ -169,7 +169,8 @@ def resolve_downsample_method(method: str) -> str:
     sort elsewhere (both bit-identical; measured in
     benchmarks/bench_indexing)."""
     if method == "auto":
-        return "merge" if jax.default_backend() == "tpu" else "sort"
+        from repro.kernels import ops
+        return "merge" if ops.on_tpu() else "sort"
     if method not in ("merge", "sort"):
         raise ValueError(f"unknown downsample method {method!r}")
     return method

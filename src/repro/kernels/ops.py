@@ -10,7 +10,8 @@ Every op takes ``impl`` ∈ {"auto", "pallas", "xla"}:
              stay runnable everywhere).
   "xla"    — always the jnp reference path.
 
-``resolve_backend`` is the single source of that truth. The spconv entry
+``resolve_backend`` is the single source of that truth, and :func:`on_tpu`
+the package's one platform check. The spconv entry
 points also own tile selection and shape padding, so arbitrary (M, Cout)
 work: M is padded to the row-tile with ``-1`` kernel-map rows (gather-
 skipped, zero output, sliced off), and Cout falls back to a single
@@ -31,16 +32,24 @@ from .ws_scatter_gemm import ws_scatter_gemm as _ws_pallas
 from .flash_attention import flash_attention as _fa_pallas
 
 
+def on_tpu() -> bool:
+    """Whether JAX's default backend is a TPU — the one platform check in
+    the package. Every platform-dependent choice (Pallas compiled or
+    interpreted, merge or sort downsample, which backends the tuner times)
+    asks here, so a test steers them all by patching this function."""
+    return jax.default_backend() == "tpu"
+
+
 def resolve_backend(impl: str) -> Tuple[bool, bool]:
     """(use_pallas, interpret) for an ``impl``/``backend`` string."""
     if impl not in ("auto", "xla", "pallas"):
         raise ValueError(f"unknown backend {impl!r}; want auto|xla|pallas")
-    on_tpu = jax.default_backend() == "tpu"
+    tpu = on_tpu()
     if impl == "xla":
         return False, False
     if impl == "pallas":
-        return True, not on_tpu
-    return on_tpu, False
+        return True, not tpu
+    return tpu, False
 
 
 def _row_tile(M: int, bm: int) -> Tuple[int, int]:
@@ -76,7 +85,7 @@ def spconv_os_fused(features: jax.Array, m: jax.Array, weights: jax.Array,
 
 
 def spconv_ws_fused(features: jax.Array, m: jax.Array, weights: jax.Array,
-                    *, capacity: int, impl: str = "auto", bc: int = 0,
+                    *, capacity: int, impl: str = "auto", bm: int = 0,
                     bn: int = 0, interpret: bool = False) -> jax.Array:
     """WS dataflow, fused compact+GEMM+merge. XLA fallback = the scan in
     core.dataflow.weight_stationary (imported lazily to avoid a cycle)."""
@@ -86,7 +95,7 @@ def spconv_ws_fused(features: jax.Array, m: jax.Array, weights: jax.Array,
         return weight_stationary(features, m, weights, capacity=capacity)
     bn = _col_tile(weights.shape[-1], bn)
     out = _ws_pallas(features, m, weights, capacity=capacity,
-                     bc=bc or 128, bn=bn, interpret=interpret or interp)
+                     bm=bm or 128, bn=bn, interpret=interpret or interp)
     return out.astype(features.dtype)
 
 

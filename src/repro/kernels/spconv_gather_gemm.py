@@ -6,28 +6,36 @@ happens *inside* the kernel, per tile, straight out of HBM-resident
 intermediate that the unfused path (XLA gather + ``masked_group_gemm``)
 writes to and re-reads from HBM.
 
-  grid = (M/bm, Cout/bn, Kd)        — out tile revisited along the Kd axis
-  m block   (bm, 1)    SMEM         — int32 kernel-map column (DMA indices)
-  F_in      [N, Cin]   HBM (ANY)    — gathered row-by-row by async copy
-  w block   (1, Cin, bn) VMEM
-  out block (bm, bn)   VMEM         — fp32 scratch accumulator
+  grid = (M/bm, Cout/bn, Kd)            — out tile revisited along Kd
+  m block   (1, bm)        SMEM         — kernel-map column k of row tile i,
+                                          from the [Kd, M/bm, 1, bm] view
+  F_in      [N, 1, Cin_p]  HBM (ANY)    — gathered row-by-row by async copy
+  w block   (1, Cin, bn)   VMEM
+  out block (bm, bn)       VMEM         — fp32 scratch accumulator
 
-Per (tile, offset) the kernel walks the bm index scalars in SMEM and issues
-one row DMA per *valid* entry; invalid entries (m < 0) skip the HBM read
-entirely and zero the staging row in VMEM — the mask is applied in-register
-at gather time, never in memory. One MXU matmul per (offset, tile)
-accumulates into fp32 scratch, flushed on the last offset.
+TPU layout: Mosaic tiles the last two dims of every buffer by (8, 128), and
+a DMA may only slice whole tiles there. ``F_in`` is therefore zero-padded
+to ``Cin_p`` (a multiple of 128 lanes) and viewed as ``[N, 1, Cin_p]``, and
+the VMEM staging buffer is ``(bm, 1, Cin_p)``: one gathered row is then an
+index along an untiled leading dim on both ends of the copy. The matmul
+reads back only the first ``Cin`` lanes, so the padding never enters the
+arithmetic.
+
+Per (tile, offset) the kernel walks the bm index scalars in SMEM, starts
+one row DMA per *valid* entry and zeroes the staging row of every invalid
+entry (m < 0) — the mask is applied in-register at gather time, never in
+memory — then waits for all the copies before one MXU matmul accumulates
+into fp32 scratch, flushed on the last offset. All copies of a tile share
+one DMA semaphore, so they are in flight together rather than one by one.
 
 HBM traffic vs the unfused path: the ``2·M·Kd·Cin`` intermediate bytes
 (write + re-read) disappear, and gather reads drop from ``M·Kd·Cin`` to
 ``nnz·Cin`` (only valid kernel-map entries are fetched). See
 ``core.dataflow.hbm_bytes_model`` for the accounting used by benchmarks.
 
-Alignment: choose bm a multiple of 8 (fp32 sublane) and bn ≤ Cout with
-Cout % bn == 0; ``kernels.ops.spconv_os_fused`` pads M and picks tiles so
-arbitrary shapes work. Production note: the per-row DMAs are issued from a
-sequential loop — a double-buffered variant would overlap them with the
-MXU; on the CPU interpreter this is moot.
+Alignment: bm must be a multiple of 8 (fp32 sublane) and divide M; bn ≤
+Cout with Cout % bn == 0; ``kernels.ops.spconv_os_fused`` pads M and
+picks tiles so arbitrary shapes work.
 
 Backward engine: the OS custom VJP (``core.dataflow``) runs this same
 kernel for dF_in — the operands become (cotangents g, the transposed
@@ -44,34 +52,68 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+LANES = 128
 
-def _kernel(m_ref, f_hbm, w_ref, o_ref, acc_ref, g_ref, sem, *, n_k, n_in, bm):
+
+def lane_rows(x: jax.Array) -> jax.Array:
+    """``[N, C]`` → ``[N, 1, C_p]``, C zero-padded to a multiple of 128
+    lanes: the HBM layout in which one row is a whole-tile DMA slice
+    (module doc). Shared with ``ws_scatter_gemm``."""
+    n, c = x.shape
+    cp = -(-c // LANES) * LANES
+    if cp != c:
+        x = jnp.pad(x, ((0, 0), (0, cp - c)))
+    return x.reshape(n, 1, cp)
+
+
+def tile_columns(m: jax.Array, bm: int) -> jax.Array:
+    """``[M, Kd]`` kernel map → ``[Kd, M/bm, 1, bm]``: the (1, bm) block of
+    one offset column over one row tile has last two dims equal to the
+    array's, which is the form an SMEM block may take."""
+    M, Kd = m.shape
+    return m.T.reshape(Kd, M // bm, 1, bm)
+
+
+def row_copy(f_hbm, dst, idx, sem, n_in: int):
+    """DMA descriptor for one gathered feature row ``F_in[idx]`` → ``dst``."""
+    return pltpu.make_async_copy(f_hbm.at[jnp.clip(idx, 0, n_in - 1)], dst,
+                                 sem)
+
+
+def _kernel(m_ref, f_hbm, w_ref, o_ref, acc_ref, g_ref, sem,
+            *, n_k, n_in, bm, cin):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def gather(r, carry):
-        idx = m_ref[r, 0]
+    def start(r, carry):
+        idx = m_ref[0, r]
 
         @pl.when(idx >= 0)
         def _fetch():
-            row = jnp.clip(idx, 0, n_in - 1)
-            cp = pltpu.make_async_copy(
-                f_hbm.at[pl.ds(row, 1), :], g_ref.at[pl.ds(r, 1), :], sem)
-            cp.start()
-            cp.wait()
+            row_copy(f_hbm, g_ref.at[r], idx, sem, n_in).start()
 
         @pl.when(idx < 0)
         def _blank():
-            g_ref[pl.ds(r, 1), :] = jnp.zeros_like(g_ref[pl.ds(r, 1), :])
+            g_ref[r] = jnp.zeros(g_ref.shape[1:], g_ref.dtype)
 
         return carry
 
-    jax.lax.fori_loop(0, bm, gather, 0)
-    acc_ref[...] += jnp.dot(g_ref[...], w_ref[0],
-                            preferred_element_type=jnp.float32)
+    def wait(r, carry):
+        idx = m_ref[0, r]
+
+        @pl.when(idx >= 0)
+        def _done():
+            row_copy(f_hbm, g_ref.at[r], idx, sem, n_in).wait()
+
+        return carry
+
+    jax.lax.fori_loop(0, bm, start, 0)
+    jax.lax.fori_loop(0, bm, wait, 0)
+    g = g_ref[...].reshape(bm, g_ref.shape[-1])[:, :cin]
+    acc_ref[...] += jnp.dot(g, w_ref[0], preferred_element_type=jnp.float32)
 
     @pl.when(k == n_k - 1)
     def _flush():
@@ -93,12 +135,13 @@ def spconv_gather_gemm(
     N, Cin = features.shape
     Cout = weights.shape[-1]
     assert M % bm == 0 and Cout % bn == 0, (M, bm, Cout, bn)
+    f3 = lane_rows(features)
     grid = (M // bm, Cout // bn, Kd)
     return pl.pallas_call(
-        functools.partial(_kernel, n_k=Kd, n_in=N, bm=bm),
+        functools.partial(_kernel, n_k=Kd, n_in=N, bm=bm, cin=Cin),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, 1), lambda i, j, k: (i, k),
+            pl.BlockSpec((None, None, 1, bm), lambda i, j, k: (k, i, 0, 0),
                          memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec((1, Cin, bn), lambda i, j, k: (k, 0, j)),
@@ -107,8 +150,8 @@ def spconv_gather_gemm(
         out_shape=jax.ShapeDtypeStruct((M, Cout), features.dtype),
         scratch_shapes=[
             pltpu.VMEM((bm, bn), jnp.float32),
-            pltpu.VMEM((bm, Cin), features.dtype),
+            pltpu.VMEM((bm,) + f3.shape[1:], features.dtype),
             pltpu.SemaphoreType.DMA,
         ],
         interpret=interpret,
-    )(m, features, weights)
+    )(tile_columns(m, bm), f3, weights)

@@ -2,27 +2,36 @@
 
 The XLA ``weight_stationary`` scans offsets, and per offset materializes a
 ``[capacity, Cin]`` gathered-feature buffer in HBM before its GEMM, then
-scatter-adds into the accumulator. This kernel fuses all three stages:
+scatter-adds into the accumulator. This kernel fuses all three stages per
+output-row tile:
 
-  host side (cheap int32 XLA, no feature bytes): the per-offset compaction
-    *indices* — ``in_idx[k, c]`` (input row of the c-th valid pair of
-    offset k) and ``out_idx[k, c]`` (its output row) — via one vectorized
-    cumsum over the kernel-map validity mask. Pairs beyond ``capacity``
-    are dropped, exactly matching the XLA path's scatter-drop semantics.
+  host side (cheap int32 XLA, no feature bytes): valid pairs beyond
+    ``capacity`` are dropped first (per offset, the first ``capacity``
+    valid rows survive — exactly the XLA path's scatter-drop), then each
+    (offset, row tile) is compacted: its valid pairs move to the front of
+    the tile's ``bm`` slots in output-row order. ``in_idx`` holds their
+    input rows, ``out_row`` their tile-local output rows and ``n`` their
+    count. A kernel map has at most one pair per (output row, offset), so
+    one tile's pairs of one offset always fit its ``bm`` slots.
 
-  kernel: grid (Cout/bn, Ks, capacity/bc), innermost-first iteration, so
-    for each output-channel tile the kernel sweeps every (offset, chunk)
-    sequentially — TPU grids are sequential, which is what makes the merge
-    deterministic without atomics. Per step it DMAs the chunk's valid
-    input rows from HBM-resident F_in into VMEM (empty slack slots skip
-    the DMA), runs one MXU matmul against the resident W[k] tile, and
-    merges each product row into the fp32 output block at its out_idx row
-    (rows are unique within an offset ⇒ plain read-modify-write).
+  kernel: grid (M/bm, Cout/bn, Ks) — the (bm, bn) fp32 output block stays
+    VMEM-resident while the offset axis is swept, and the offsets are
+    visited in order, so every output row sums its contributions in the
+    XLA scan's order. Per step it DMAs the ``n`` valid input rows from
+    HBM-resident F_in into VMEM (an empty (tile, offset) skips the step),
+    runs one MXU matmul against W[k] into the ``part`` scratch, and merges
+    each product row into the output block at its ``out_row``.
+
+  grid = (M/bm, Cout/bn, Ks)
+  n / in_idx / out_row blocks  SMEM  — from [Ks, M/bm, 1, ·] views
+  F_in       [N, 1, Cin_p]     HBM   — ``spconv_gather_gemm.lane_rows``
+  w block    (1, Cin, bn)      VMEM
+  out block  (bm, bn) fp32     VMEM
 
 vs the XLA scan this removes the per-offset ``[capacity, Cin]`` HBM
 intermediate and the ``Ks`` scatter passes over the ``[M, Cout]``
-accumulator — the output block stays VMEM-resident across the whole sweep
-(VMEM bound: M·bn·4 bytes; pick bn accordingly for large M).
+accumulator. VMEM holds one output tile, so the kernel's footprint does not
+grow with M.
 
 Accumulation is fp32 throughout (the output is fp32, cast by the caller),
 matching the XLA path bit-for-bit on valid rows in interpret mode.
@@ -42,97 +51,101 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .spconv_gather_gemm import lane_rows, row_copy, tile_columns
 
-def _kernel(in_idx_ref, out_idx_ref, f_hbm, w_ref, o_ref, g_ref, sem,
-            *, n_in, n_out, bc, bn):
-    k = pl.program_id(1)
-    c = pl.program_id(2)
 
-    @pl.when((k == 0) & (c == 0))
+def _kernel(n_ref, in_ref, row_ref, f_hbm, w_ref, o_ref, g_ref, part_ref,
+            sem, *, n_in, cin):
+    k = pl.program_id(2)
+
+    @pl.when(k == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    def gather(r, carry):
-        @pl.when(out_idx_ref[0, r] < n_out)   # slack slots: no HBM read
-        def _fetch():
-            row = jnp.clip(in_idx_ref[0, r], 0, n_in - 1)
-            cp = pltpu.make_async_copy(
-                f_hbm.at[pl.ds(row, 1), :], g_ref.at[pl.ds(r, 1), :], sem)
-            cp.start()
-            cp.wait()
+    n = n_ref[0, 0]
 
-        return carry
+    @pl.when(n > 0)
+    def _offset():
+        def start(r, carry):
+            row_copy(f_hbm, g_ref.at[r], in_ref[0, r], sem, n_in).start()
+            return carry
 
-    jax.lax.fori_loop(0, bc, gather, 0)
-    part = jnp.dot(g_ref[...], w_ref[0],
-                   preferred_element_type=jnp.float32)       # (bc, bn)
+        def wait(r, carry):
+            row_copy(f_hbm, g_ref.at[r], in_ref[0, r], sem, n_in).wait()
+            return carry
 
-    def merge(r, carry):
-        orow = out_idx_ref[0, r]
-        safe = jnp.minimum(orow, n_out - 1)
-        row = jax.lax.dynamic_slice(part, (r, 0), (1, bn))
-        # slack slots (orow == n_out) carry uninitialized scratch — select,
-        # don't scale, so garbage NaNs can't leak through a 0 multiply.
-        row = jnp.where(orow < n_out, row, jnp.zeros_like(row))
-        o_ref[pl.ds(safe, 1), :] = o_ref[pl.ds(safe, 1), :] + row
-        return carry
+        jax.lax.fori_loop(0, n, start, 0)
+        jax.lax.fori_loop(0, n, wait, 0)
+        # staging rows >= n hold stale data; their products are never merged
+        g = g_ref[...].reshape(g_ref.shape[0], g_ref.shape[-1])[:, :cin]
+        part_ref[...] = jnp.dot(g, w_ref[0],
+                                preferred_element_type=jnp.float32)
 
-    jax.lax.fori_loop(0, bc, merge, 0)
+        def merge(r, carry):
+            row = row_ref[0, r]
+            o_ref[pl.ds(row, 1), :] = (o_ref[pl.ds(row, 1), :]
+                                       + part_ref[pl.ds(r, 1), :])
+            return carry
+
+        jax.lax.fori_loop(0, n, merge, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("capacity", "bc", "bn", "interpret"))
+@functools.partial(jax.jit, static_argnames=("capacity", "bm", "bn",
+                                             "interpret"))
 def ws_scatter_gemm(
     features: jax.Array,  # [N, Cin] HBM-resident input features
     m: jax.Array,         # int32 [M, Ks] kernel-map column subset
     weights: jax.Array,   # [Ks, Cin, Cout]
     *,
     capacity: int,
-    bc: int = 128,
+    bm: int = 128,
     bn: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
     """WS dataflow with static per-offset pair capacity, fully fused.
 
     Valid pairs beyond ``capacity`` are dropped (identical to the XLA
-    path). Returns fp32 ``[M, Cout]`` — cast at the call site.
+    path). M is padded to the ``bm`` row tile internally. Returns fp32
+    ``[M, Cout]`` — cast at the call site.
     """
     M, Ks = m.shape
     N, Cin = features.shape
     Cout = weights.shape[-1]
-    cap = ((capacity + bc - 1) // bc) * bc   # tables padded with slack
-    assert Cout % bn == 0, (Cout, bn)
+    assert bm % 8 == 0 and Cout % bn == 0, (bm, Cout, bn)
+    Mp = -(-M // bm) * bm
+    n_tiles = Mp // bm
 
-    # --- host-side compaction indices (int32 only; no feature movement) ---
+    # --- host-side compaction (int32 only; no feature movement) ---
     valid = m >= 0
-    dest = jnp.where(valid, jnp.cumsum(valid, axis=0) - 1, capacity)
-    # overflow pairs keep dest >= capacity and fall off via mode="drop",
-    # matching weight_stationary's scatter-drop exactly.
-    dest = jnp.where(dest >= capacity, cap, dest)
-    kk = jnp.broadcast_to(jnp.arange(Ks, dtype=jnp.int32)[None, :], (M, Ks))
-    rows = jnp.broadcast_to(jnp.arange(M, dtype=jnp.int32)[:, None], (M, Ks))
-    in_idx = jnp.zeros((Ks, cap), jnp.int32).at[kk.T, dest.T].set(
-        jnp.clip(m, 0).T, mode="drop")
-    out_idx = jnp.full((Ks, cap), M, jnp.int32).at[kk.T, dest.T].set(
-        rows.T, mode="drop")
+    kept = jnp.where(valid & (jnp.cumsum(valid, axis=0) <= capacity), m, -1)
+    kept = jnp.pad(kept, ((0, Mp - M), (0, 0)), constant_values=-1)
+    t = tile_columns(kept, bm)                    # [Ks, M/bm, 1, bm]
+    rows = jnp.arange(bm, dtype=jnp.int32)
+    # valid rows first, each group in row order
+    out_row = jnp.argsort(jnp.where(t >= 0, rows, bm + rows),
+                          axis=-1).astype(jnp.int32)
+    in_idx = jnp.take_along_axis(t, out_row, axis=-1)
+    n = (t >= 0).sum(axis=-1, keepdims=True, dtype=jnp.int32)
 
-    grid = (Cout // bn, Ks, cap // bc)
+    f3 = lane_rows(features)
+    smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
     out = pl.pallas_call(
-        functools.partial(_kernel, n_in=N, n_out=M, bc=bc, bn=bn),
-        grid=grid,
+        functools.partial(_kernel, n_in=N, cin=Cin),
+        grid=(n_tiles, Cout // bn, Ks),
         in_specs=[
-            pl.BlockSpec((1, bc), lambda j, k, c: (k, c),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, bc), lambda j, k, c: (k, c),
-                         memory_space=pltpu.SMEM),
+            smem((None, None, 1, 1), lambda i, j, k: (k, i, 0, 0)),
+            smem((None, None, 1, bm), lambda i, j, k: (k, i, 0, 0)),
+            smem((None, None, 1, bm), lambda i, j, k: (k, i, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec((1, Cin, bn), lambda j, k, c: (k, 0, j)),
+            pl.BlockSpec((1, Cin, bn), lambda i, j, k: (k, 0, j)),
         ],
-        out_specs=pl.BlockSpec((M, bn), lambda j, k, c: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((M, Cout), jnp.float32),
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((Mp, Cout), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((bc, Cin), features.dtype),
+            pltpu.VMEM((bm,) + f3.shape[1:], features.dtype),
+            pltpu.VMEM((bm, bn), jnp.float32),
             pltpu.SemaphoreType.DMA,
         ],
         interpret=interpret,
-    )(in_idx, out_idx, features, weights)
-    return out
+    )(n, in_idx, out_row, f3, weights)
+    return out[:M]

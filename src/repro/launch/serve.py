@@ -14,6 +14,7 @@ import numpy as np
 import jax
 
 from repro import configs
+from repro.compile_cache import enable_compile_cache
 from repro.dist.sharding import param_shardings, sharding_ctx
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import transformer as tf
@@ -30,6 +31,7 @@ def main():
     ap.add_argument("--cache-len", type=int, default=256)
     ap.add_argument("--max-new", type=int, default=16)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = configs.get_config(args.arch, smoke=args.smoke)
     assert not cfg.embedding_inputs, \

@@ -21,6 +21,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import configs
 from repro.ckpt import CheckpointManager
+from repro.compile_cache import enable_compile_cache
 from repro.data.tokens import DataConfig, batch_at
 from repro.dist.sharding import param_shardings, sharding_ctx
 from repro.launch.mesh import make_host_mesh, make_production_mesh
@@ -41,6 +42,7 @@ def main():
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--fsdp", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if os.environ.get("JAX_COORDINATOR"):
         jax.distributed.initialize()  # multi-host pod entry

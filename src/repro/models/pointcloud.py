@@ -113,11 +113,10 @@ def sparse_resnet21(in_channels: int = 4, n_classes: int = 20,
 def minkunet42(in_channels: int = 4, n_classes: int = 20,
                width: Sequence[int] = (32, 64, 128, 256),
                dataflow: str = "os", backend: str = "auto") -> PointCloudNet:
-    # NB: the paper finds UNet favors weight-stationary **on GPU**; on TPU
-    # (no atomics — WS merges via scatter) output-stationary wins by ~1000×
-    # collective/memory terms in the pod-scale dry-run (§Perf SpC iter-1),
-    # so "os" is the TPU default. Pass dataflow="ws" to reproduce the GPU
-    # preference structurally.
+    # NB: the paper finds UNet favors weight-stationary **on GPU**. On TPU
+    # there are no atomics, so WS merges row by row; "os" is the default
+    # on that argument alone — no chip run has compared the two yet
+    # (ROADMAP 1c). Pass dataflow="ws" to reproduce the GPU preference.
     """Encoder (4 downsample stages) + decoder (4 inverse-conv stages) with
     submanifold pairs at each level — 42 SpC layers total."""
     specs: List[SpConvSpec] = [

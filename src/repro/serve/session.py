@@ -77,6 +77,7 @@ from repro.core.network_plan import NetworkPlan
 from repro.core.packing import BitLayout
 from repro.core.sparse_tensor import SparseTensor, ensure_sparse_tensor
 from repro.core.spconv import SpConvSpec
+from repro.kernels import ops
 from repro.kernels.segsum import SegmentSpec
 from repro.models.pointcloud import (PointCloudNet, init_pointcloud,
                                      packed_segments, pointcloud_forward)
@@ -491,6 +492,11 @@ def compile_network(
                         metrics=metrics)
 
 
+def _tunable_backends() -> Tuple[str, ...]:
+    """Backends worth timing: off-TPU "pallas" would time the interpreter."""
+    return ("xla", "pallas") if ops.on_tpu() else ("xla",)
+
+
 def _tune_segment(seg_spec: SegmentSpec, tune_sample: SparseTensor, *,
                   min_bucket: int) -> SegmentSpec:
     """Measure the segment-engine backend on the sample's V0 segmentation
@@ -498,10 +504,8 @@ def _tune_segment(seg_spec: SegmentSpec, tune_sample: SparseTensor, *,
     stp = tune_sample.pad_to(bucket_capacity(tune_sample.capacity,
                                              min_bucket=min_bucket))
     seg = packed_segments(stp.packed, stp.count, stp.layout)
-    on_tpu = jax.default_backend() == "tpu"
     res = tune_segment_backend_measure(
-        stp.features, seg, q=seg_spec.q,
-        backends=("xla", "pallas") if on_tpu else ("xla",))
+        stp.features, seg, q=seg_spec.q, backends=_tunable_backends())
     return dataclasses.replace(seg_spec, backend=res.backend)
 
 
@@ -526,15 +530,14 @@ def _tune_specs(net: PointCloudNet, layout: BitLayout, params: dict,
     plan = build_network_plan(stp.packed, specs=net.conv_specs(),
                               layout=layout, engine=engine,
                               downsample_method=downsample_method)
-    on_tpu = jax.default_backend() == "tpu"
+    backends = _tunable_backends()
     tuned = []
     for s in net.specs:
         kmap = plan.kmaps[s.name]
         if tuner == "cost_model":
             res = tune_layer_cost_model(
                 kmap, K=s.K, stride=s.offset_stride, cin=s.cin, cout=s.cout,
-                backends=("xla", "pallas") if on_tpu else ("xla",),
-                submanifold=s.submanifold)
+                backends=backends, submanifold=s.submanifold)
         else:
             feats = jax.random.normal(jax.random.key(hash(s.name) & 0xffff),
                                       (plan.coords[s.m_in].capacity, s.cin),
@@ -545,7 +548,6 @@ def _tune_specs(net: PointCloudNet, layout: BitLayout, params: dict,
             res = tune_layer_measure(
                 feats, kmap, params[s.name]["w"], K=s.K,
                 stride=s.offset_stride, ws_capacity=kmap.m.shape[0],
-                backends=("xla", "pallas") if on_tpu else ("xla",),
-                coords=coords, submanifold=s.submanifold)
+                backends=backends, coords=coords, submanifold=s.submanifold)
         tuned.append(apply_tuning(s, res))
     return tuple(tuned)

@@ -58,7 +58,7 @@ def test_ws_scatter_bitmatch(K, dtype, capacity):
         np.asarray((m >= 0).sum(0)).max()) // 2 or 1
     f = jnp.asarray(rng.normal(size=(N, Cin)), dtype)
     w = jnp.asarray(rng.normal(size=(K ** 3, Cin, Cout)) / np.sqrt(Cin), dtype)
-    got = ws_scatter_gemm(f, m, w, capacity=cap, bc=64, bn=Cout,
+    got = ws_scatter_gemm(f, m, w, capacity=cap, bm=64, bn=Cout,
                           interpret=True).astype(dtype)
     want = weight_stationary(f, m, w, capacity=cap)
     np.testing.assert_array_equal(np.asarray(got, np.float32),

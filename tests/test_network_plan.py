@@ -35,6 +35,22 @@ def test_network_plan_all_engines_agree():
         np.testing.assert_array_equal(mz, np.asarray(plans["hash"].kmaps[name].m))
 
 
+@pytest.mark.parametrize("engine", ["zdelta_pallas", "zdelta_pallas_window"])
+def test_zdelta_pallas_engines_refused_on_tpu(monkeypatch, engine):
+    """Their kernels do not compile for TPU: planning with them there must
+    raise, not fall back to the interpreter."""
+    from repro.kernels import ops
+    sc = scenes.indoor_scene(12, room=(32, 32, 16))
+    jax.clear_caches()
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    try:
+        with pytest.raises(NotImplementedError, match="do not compile"):
+            build_network_plan(scenes.pack_scene(sc), specs=_specs()[:1],
+                               layout=sc.layout, engine=engine)
+    finally:
+        jax.clear_caches()
+
+
 def test_network_plan_matches_brute_force_inverse_conv():
     """The l4_up inverse-conv map must match brute force with the fine-side
     offset stride."""

@@ -175,7 +175,7 @@ def test_pack_unpack_roundtrip_at_field_boundaries(layout, data):
                   st.sampled_from(_boundary_vals(layout.by, layout.guard)),
                   st.sampled_from(_boundary_vals(layout.bz, layout.guard))),
         min_size=1, max_size=64)), np.int64)
-    ctx = (jax.experimental.enable_x64() if layout.bits_total > 31
+    ctx = (jax.enable_x64(True) if layout.bits_total > 31
            else contextlib.nullcontext())
     with ctx:
         p = np.asarray(pack(jnp.asarray(c), layout))

@@ -164,7 +164,7 @@ def test_pack_unpack_roundtrip_at_field_boundaries(layout):
     c = np.array([(x, y, z) for x in bx for y in by for z in bz], np.int64)
     want_dtype = np.int32 if layout.bits_total <= 31 else np.int64
     # the 64-bit packing path needs x64 enabled (packing module doc)
-    ctx = (jax.experimental.enable_x64() if layout.bits_total > 31
+    ctx = (jax.enable_x64(True) if layout.bits_total > 31
            else contextlib.nullcontext())
     with ctx:
         for sid in range(min(1 << layout.bb, 3)):
