@@ -227,17 +227,27 @@ def build_network_plan(
     from each spec (``spec.window``, 0 = auto; the tuner's
     ``plan_superwindow`` sizes it exactly). Submanifold layers with
     ``spec.symmetry`` use the §5.4 half-search for the zdelta engines.
+
+    The plan's operations carry the ``jax.named_scope`` names
+    ``plan/sort``, ``plan/downsample`` and ``plan/search`` in their op-name
+    metadata, so a device trace can charge their time to each stage.
     """
-    v0 = build_coord_set(packed_raw)
+    with jax.named_scope("plan/sort"):
+        v0 = build_coord_set(packed_raw)
     levels = plan_levels(specs)
-    coords: Dict[int, CoordSet] = dict(zip(
-        levels, downsample_all(v0, layout, levels, method=downsample_method)))
+    with jax.named_scope("plan/downsample"):
+        coords: Dict[int, CoordSet] = dict(zip(
+            levels, downsample_all(v0, layout, levels,
+                                   method=downsample_method)))
 
     kmaps: Dict[str, KernelMap] = {}
     stats: Dict[str, jax.Array] = {}
     for s in specs:
         inputs, outputs = coords[s.m_in], coords[s.m_out]
-        m, ovf = _layer_map(inputs, outputs, s, layout, engine)
+        # One scope for every layer's search: XLA merges identical searches
+        # of different layers, so a per-layer name would be ambiguous.
+        with jax.named_scope("plan/search"):
+            m, ovf = _layer_map(inputs, outputs, s, layout, engine)
         kmaps[s.name] = KernelMap(m=m, out_count=outputs.count,
                                   in_count=inputs.count)
         stats[s.name] = ovf

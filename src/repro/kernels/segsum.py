@@ -264,6 +264,7 @@ def segment_sum_pallas(x: jax.Array, sid: jax.Array, starts: jax.Array, *,
         scratch_shapes=[pltpu.VMEM((S_pad, C), jnp.float32),
                         pltpu.VMEM((S_pad, C), jnp.float32)],
         interpret=interpret,
+        name="segment_sum_pallas",
     )(sid.astype(jnp.int32)[:, None], starts2, x.astype(jnp.float32))
     return out[:S]
 

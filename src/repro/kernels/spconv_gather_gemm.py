@@ -154,4 +154,5 @@ def spconv_gather_gemm(
             pltpu.SemaphoreType.DMA,
         ],
         interpret=interpret,
+        name="spconv_gather_gemm",
     )(tile_columns(m, bm), f3, weights)

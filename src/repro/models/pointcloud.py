@@ -359,7 +359,12 @@ def pointcloud_forward(params: dict, net: PointCloudNet, plan,
     lemma). Without it (legacy single-scene calls), statistics span the
     whole valid prefix — the same engine with S=1. ``segment`` selects the
     engine backend/chunking (``kernels.segsum.SegmentSpec``, tuner-owned
-    via the session)."""
+    via the session).
+
+    Each layer's operations run under the ``jax.named_scope`` names
+    ``<layer>/conv`` and ``<layer>/norm``, the classifier under ``head``
+    and the scene segmentation under ``plan/segments``: the op-name
+    metadata a device trace charges their time by."""
     from repro.core.sparse_tensor import SparseTensor
 
     if isinstance(features, SparseTensor):
@@ -386,7 +391,8 @@ def pointcloud_forward(params: dict, net: PointCloudNet, plan,
             "session API (repro.serve.compile_network) pads both "
             "consistently; if hand-stitching, pad features to the plan's "
             "V0 capacity.")
-    segs = level_segments(plan, layout) if (layout and layout.bb) else {}
+    with jax.named_scope("plan/segments"):
+        segs = level_segments(plan, layout) if (layout and layout.bb) else {}
     skips: Dict[int, jax.Array] = {}
     x = features
     for spec in net.specs:
@@ -395,13 +401,16 @@ def pointcloud_forward(params: dict, net: PointCloudNet, plan,
             skip = skips.get(spec.m_in)
             if skip is not None:
                 x = jnp.concatenate([x, skip], axis=-1)
-        x = apply_spconv(params[spec.name], spec, x, kmap)
-        x = _relu_bn(x, kmap.out_count, segs.get(spec.m_out),
-                     segment=segment)
+        with jax.named_scope(f"{spec.name}/conv"):
+            x = apply_spconv(params[spec.name], spec, x, kmap)
+        with jax.named_scope(f"{spec.name}/norm"):
+            x = _relu_bn(x, kmap.out_count, segs.get(spec.m_out),
+                         segment=segment)
         if spec.name.startswith("enc") and spec.name.endswith("_b"):
             skips[spec.m_out] = x
         if spec.name.startswith("stem"):
             skips[0] = x
     # head dW reduces over the capacity axis — rowdot_matmul keeps that
     # contraction's grouping capacity-stable (core.dataflow doc)
-    return rowdot_matmul(x, params["head"].astype(x.dtype))
+    with jax.named_scope("head"):
+        return rowdot_matmul(x, params["head"].astype(x.dtype))

@@ -2,7 +2,8 @@
 
 One `MetricsRegistry` per pipeline (session → engine/trainer → ckpt all
 share it); `span(...)` context managers time host-side phases into
-registry histograms — always OUTSIDE jitted graphs (see obs.trace);
+registry histograms — always OUTSIDE jitted graphs (see obs.trace), and
+annotate a running profiler trace where jax is loaded;
 `snapshot()` / `to_prometheus_text()` export everything. Stdlib-only.
 """
 from .metrics import (

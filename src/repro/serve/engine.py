@@ -109,9 +109,12 @@ never disagree, and ``+=`` / ``=`` on them keeps working. On top of the
 counters the engine records, per the ROADMAP's serving-hardening item:
 
 * ``serve_queue_wait`` histogram — submit→drain time per request;
-* ``serve/pack`` / ``serve/dispatch`` histograms — host pack time and
-  per-attempt session-call time (``obs.trace.span``, host side only —
-  never inside the jitted graph, see ``repro.obs.trace``);
+* ``serve/pack`` / ``serve/dispatch`` / ``serve/answer`` histograms —
+  host pack time, per-attempt session-call time, and the logits' fetch
+  plus their unpacking on the host (``obs.trace.span``, host side
+  only — never inside the jitted graph, see ``repro.obs.trace``; each
+  also lands in a running profiler trace, tagged with the batch's
+  sequence number);
 * ``serve_latency_<outcome>`` histograms — submit→terminal-outcome
   latency, one histogram per outcome so SLO percentiles aren't polluted
   by shed/expired requests;
@@ -129,6 +132,7 @@ unchanged (pinned in tests/test_obs.py).
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -325,6 +329,9 @@ class PointCloudServeEngine:
         self._transient = transient or _default_transient
         self.batches_run = 0
         self.scenes_served = 0
+        # sequence number of each batch drained or bisected: the engine's
+        # spans carry it into the profiler trace (repro.obs.trace)
+        self._batch_seq = itertools.count()
         self.packs_overlapped = 0
         # degraded-mode counters (module doc) — the observability surface
         self.admitted = 0
@@ -471,25 +478,29 @@ class PointCloudServeEngine:
                 r.downsampled = True
                 self.downsampled += 1
 
-    def _pack(self, batch: List[PointCloudRequest]) -> SparseTensor:
+    def _pack(self, batch: List[PointCloudRequest], seq: int) -> SparseTensor:
         if self._ladder is not None and self._ladder.rung >= 3:
             self._downsample(batch)
-        with span("serve/pack", self.metrics):
+        with span("serve/pack", self.metrics, batch=seq):
             return SparseTensor.from_point_clouds(
                 [(r.coords, r.features) for r in batch], self.session.layout,
                 validate=self.validate)
 
-    def _answer(self, batch: List[PointCloudRequest], out, health) -> None:
+    def _answer(self, batch: List[PointCloudRequest], out, health,
+                seq: int) -> None:
         """Scatter per-scene logits back onto the requests. Materializes
-        device results (the blocking point the pipelined run overlaps)."""
-        for req, scene in zip(batch, out.unbatch()):
-            n = int(scene.count)
-            req.logits = np.asarray(scene.features)[:n]
-            req.voxels, _ = scene.coords()
-            req.health = health
-            req.done = True
-            req.outcome = "ok"
-            self._record_latency(req)
+        device results (the blocking point the pipelined run overlaps):
+        the ``serve/answer`` span holds the logits' fetch to the host and
+        the unpacking of each scene's voxels."""
+        with span("serve/answer", self.metrics, batch=seq):
+            for req, scene in zip(batch, out.unbatch()):
+                n = int(scene.count)
+                req.logits = np.asarray(scene.features)[:n]
+                req.voxels, _ = scene.coords()
+                req.health = health
+                req.done = True
+                req.outcome = "ok"
+                self._record_latency(req)
         self.metrics.rate("serve_qps").mark(len(batch))
         if health is not None:
             self.overflow_replans += health.replans
@@ -538,7 +549,7 @@ class PointCloudServeEngine:
             raise box["exc"]
         return box["out"]
 
-    def _call_session(self, st: SparseTensor):
+    def _call_session(self, st: SparseTensor, seq: int):
         """One session call with capped-backoff retry of transient faults.
         Raises only after ``max_retries`` transient failures (or on the
         first non-transient one) — bisection takes over from there. A
@@ -547,7 +558,7 @@ class PointCloudServeEngine:
         attempt = 0
         while True:
             try:
-                with span("serve/dispatch", self.metrics):
+                with span("serve/dispatch", self.metrics, batch=seq):
                     return self._watched(st)
             except Exception as e:
                 if (isinstance(e, DispatchTimeoutError)
@@ -559,7 +570,8 @@ class PointCloudServeEngine:
                                 self.backoff_cap))
                 attempt += 1
 
-    def _serve_batch(self, batch: List[PointCloudRequest]) -> None:
+    def _serve_batch(self, batch: List[PointCloudRequest],
+                     seq: int) -> None:
         """Pack + dispatch with full fault isolation; never raises.
 
         Ingest rejections are attributed exactly (``ValidationError.scene_index``),
@@ -569,18 +581,18 @@ class PointCloudServeEngine:
         if not batch:
             return
         try:
-            st = self._pack(batch)
+            st = self._pack(batch, seq)
         except ValidationError as e:
             idx = e.scene_index if e.scene_index is not None else 0
             bad = batch[idx]
             self._finish(bad, "invalid", str(e))
             self.invalid += 1
-            self._serve_batch(batch[:idx] + batch[idx + 1:])
+            self._serve_batch(batch[:idx] + batch[idx + 1:], seq)
             return
         except Exception as e:
             self._isolate(batch, e, "invalid")
             return
-        self._dispatch(batch, st)
+        self._dispatch(batch, st, seq)
 
     def _sync_breaker(self) -> None:
         self.metrics.gauge("serve_breaker_state").set(
@@ -594,7 +606,7 @@ class PointCloudServeEngine:
         self._sync_breaker()
 
     def _dispatch(self, batch: List[PointCloudRequest],
-                  st: SparseTensor) -> None:
+                  st: SparseTensor, seq: int) -> None:
         """Run one packed batch; on persistent failure bisect down to the
         poisoned request. Never raises. Gated by the circuit breaker
         (batches fail fast as ``rejected_open`` while it is open); a
@@ -614,7 +626,7 @@ class PointCloudServeEngine:
                     self.rejected_open += 1
                 return
         try:
-            out, health = self._call_session(st)
+            out, health = self._call_session(st, seq)
         except DispatchTimeoutError as e:
             for req in batch:
                 self._finish(req, "dispatch_timeout", str(e))
@@ -628,7 +640,7 @@ class PointCloudServeEngine:
         if self._breaker is not None:
             self._breaker.record_success()
             self._sync_breaker()
-        self._answer(batch, out, health)
+        self._answer(batch, out, health, seq)
 
     def _isolate(self, batch: List[PointCloudRequest], exc: BaseException,
                  outcome: str) -> None:
@@ -646,8 +658,8 @@ class PointCloudServeEngine:
                 self.invalid += 1
             return
         mid = len(batch) // 2
-        self._serve_batch(batch[:mid])
-        self._serve_batch(batch[mid:])
+        self._serve_batch(batch[:mid], next(self._batch_seq))
+        self._serve_batch(batch[mid:], next(self._batch_seq))
 
     # -- serving loops ----------------------------------------------------
 
@@ -677,7 +689,7 @@ class PointCloudServeEngine:
                 and now - self._sched.oldest_arrival() < max_wait):
             return expired
         batch, _, more = self._drain_batch()
-        self._serve_batch(batch)
+        self._serve_batch(batch, next(self._batch_seq))
         return batch + expired + more
 
     def run(self, requests: Sequence[PointCloudRequest]
@@ -699,36 +711,39 @@ class PointCloudServeEngine:
         pool = ThreadPoolExecutor(max_workers=1)   # single packing worker
         try:
             batch, _, _ = self._drain_batch()
-            st = self._try_pack(batch) if batch else None
+            seq = next(self._batch_seq)
+            st = self._try_pack(batch, seq) if batch else None
             while batch:
                 nxt, _, _ = self._drain_batch()
-                fut = pool.submit(self._try_pack, nxt) if nxt else None
+                nseq = next(self._batch_seq)
+                fut = (pool.submit(self._try_pack, nxt, nseq) if nxt
+                       else None)
                 if isinstance(st, SparseTensor):
                     # guarded dispatch: a session fault in batch t retries /
                     # bisects in place — batch t is answered or error-marked,
                     # never lost, and the prefetched batch t+1 proceeds.
-                    self._dispatch(batch, st)
+                    self._dispatch(batch, st, seq)
                 else:
                     # the overlapped pack failed (st is the exception):
                     # re-pack serially through the full isolation path.
-                    self._serve_batch(batch)
+                    self._serve_batch(batch, seq)
                 if fut is not None and fut.done():
                     # the pack finished while the device executed — it was
                     # fully hidden (an unfinished pack would still block in
                     # fut.result() below, i.e. not overlapped)
                     self.packs_overlapped += 1
-                batch = nxt
+                batch, seq = nxt, nseq
                 st = fut.result() if fut is not None else None
         finally:
             pool.shutdown(wait=True)
         return list(requests)
 
-    def _try_pack(self, batch: List[PointCloudRequest]):
+    def _try_pack(self, batch: List[PointCloudRequest], seq: int):
         """Pack for the overlapped worker: returns the SparseTensor or the
         exception (the worker must never raise into ``fut.result()`` —
         the main thread routes failures through ``_serve_batch``)."""
         try:
-            return self._pack(batch)
+            return self._pack(batch, seq)
         except Exception as e:
             return e
 

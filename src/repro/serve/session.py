@@ -174,6 +174,7 @@ class SpiraSession:
             self.metrics = MetricsRegistry()
         specs = self.net.conv_specs()
         self._fns: Dict[int, object] = {}
+        self._os_rows: Dict[int, dict] = {}   # escalation level -> os_rows
         self._fn = self._make_fn(0)   # escalation level 0 = the tuned plan
         self.last_health: Optional[HealthReport] = None
         self._plan_fn = jax.jit(
@@ -220,6 +221,10 @@ class SpiraSession:
                 if cols.size == 0:
                     continue
             lossy.append((s.name, int(s.ws_capacity), cols))
+        # Rows of each OS conv's kernel map (its output capacity) per input
+        # capacity: static shapes, noted when ``run`` traces for a capacity.
+        os_rows: Dict[int, Dict[str, Tuple[int, int]]] = {}
+        self._os_rows[esc] = os_rows
 
         @jax.jit
         def run(params, packed, feats):
@@ -228,18 +233,23 @@ class SpiraSession:
                                       downsample_method=method)
             logits = pointcloud_forward(params, net, plan, feats,
                                         layout=layout, segment=seg_spec)
-            out = plan.coords[out_level]
-            # Degradation signals, computed from the plan the call already
-            # built: pairs beyond ws_capacity are exactly what
-            # dataflow.ws_kept_map will zero out.
-            drops = {}
-            for name, cap, cols in lossy:
-                m = plan.kmaps[name].m
-                mc = m if cols is None else m[:, cols]
-                pairs = (mc >= 0).sum(axis=0)
-                drops[name] = jnp.maximum(pairs - cap, 0).sum() \
-                                 .astype(jnp.int32)
-            return logits, out.packed, out.count, drops, plan.stats
+            os_rows[packed.shape[0]] = {
+                s.name: (s.m_out, plan.kmaps[s.name].m.shape[0])
+                for s in specs if s.dataflow == "os"}
+            with jax.named_scope("outputs"):
+                out = plan.coords[out_level]
+                # Degradation signals, computed from the plan the call
+                # already built: pairs beyond ws_capacity are exactly what
+                # dataflow.ws_kept_map will zero out.
+                drops = {}
+                for name, cap, cols in lossy:
+                    m = plan.kmaps[name].m
+                    mc = m if cols is None else m[:, cols]
+                    pairs = (mc >= 0).sum(axis=0)
+                    drops[name] = jnp.maximum(pairs - cap, 0).sum() \
+                                     .astype(jnp.int32)
+                counts = {m: cs.count for m, cs in plan.coords.items()}
+            return logits, out.packed, out.count, drops, plan.stats, counts
 
         self._fns[esc] = run
         return run
@@ -282,21 +292,26 @@ class SpiraSession:
             stp = st.pad_to(bucket)
             fn = self._make_fn(esc)
             # Span at the host boundary around the fused plan+forward call
-            # PLUS the drop materialization (the int() casts block on the
-            # device), so it measures execution, not async dispatch.
-            # Escalated retries record separately as session/replan.
+            # and the one fetch of its plan-side scalars (WS drops, window
+            # overflows, each level's voxel count). Those depend on the
+            # plan alone, but a call's outputs all become ready when it
+            # ends, so the fetch waits for the forward too; fetching and
+            # unpacking the logits is the reader's (the serve engine's
+            # serve/answer span). Escalated retries record separately as
+            # session/replan.
             with span("session/call" if esc == 0 else "session/replan",
                       self.metrics):
-                logits, out_packed, out_count, drops, ovf = fn(
+                logits, out_packed, out_count, drops, ovf, counts = fn(
                     self.params, stp.packed, stp.features)
-                dropped = {k: int(v) for k, v in drops.items()}
+                dropped, ovf, counts = jax.device_get((drops, ovf, counts))
+            self._count_rows(self._os_rows[esc][bucket], counts)
             if sum(dropped.values()) == 0 or esc >= budget:
                 break
             esc += 1
             replans += 1
         health = HealthReport(
             bucket=bucket, escalation=esc, replans=replans,
-            ws_dropped_pairs=dropped,
+            ws_dropped_pairs={k: int(v) for k, v in dropped.items()},
             window_overflow_cells={k: int(v) for k, v in ovf.items()})
         self.last_health = health
         self._record_health(health)
@@ -305,6 +320,19 @@ class SpiraSession:
         out = SparseTensor(features=logits, packed=out_packed,
                            count=out_count, layout=self.layout)
         return out, health
+
+    def _count_rows(self, os_rows: Dict[str, Tuple[int, int]],
+                    counts: Mapping[int, int]) -> None:
+        """Rows the OS convs of one call walk (their maps' rows, the output
+        capacity), and of those the rows that hold a voxel of the output
+        level: ``spconv_rows_walked`` less ``spconv_rows_real`` is the
+        padding the OS kernel walks."""
+        walked = real = 0
+        for m_out, rows in os_rows.values():
+            walked += rows
+            real += min(int(counts[m_out]), rows)
+        self.metrics.counter("spconv_rows_walked").inc(walked)
+        self.metrics.counter("spconv_rows_real").inc(real)
 
     def _record_health(self, health: HealthReport) -> None:
         """Fold one call's HealthReport into the registry: run/replan
@@ -378,13 +406,8 @@ class SpiraSession:
         """The network plan the session would use for ``st`` (bucketed) —
         for inspection/benchmarks; the hot path fuses this into ``run``."""
         ensure_sparse_tensor(st, where="SpiraSession.plan")
-        # The standalone plan span is the plan-vs-forward split: the hot
-        # path fuses planning into session/call, so plan time is observed
-        # here (inspection/benchmarks) while session/call covers the fused
-        # plan+forward whole.
-        with span("session/plan", self.metrics):
-            stp = st.pad_to(self._bucket(st.capacity))
-            return self._plan_fn(stp.packed)
+        stp = st.pad_to(self._bucket(st.capacity))
+        return self._plan_fn(stp.packed)
 
     def _bucket(self, n: int) -> int:
         return bucket_capacity(n, min_bucket=self.min_bucket,

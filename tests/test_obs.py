@@ -209,6 +209,60 @@ def test_spans_nest_per_thread():
     assert paths == ["w"]        # not "main/w": stacks are thread-local
 
 
+def _trace_events(tmp_path, prefix):
+    """Every host event of the one trace under ``tmp_path`` whose name
+    starts with ``prefix``, as (name, stats)."""
+    import glob
+    import warnings
+    from jax.profiler import ProfileData
+    (f,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    out = []
+    with warnings.catch_warnings():
+        # jaxlib's stats type has no module; nothing to do with the trace
+        warnings.simplefilter("ignore", DeprecationWarning)
+        for plane in ProfileData.from_file(f).planes:
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith(prefix):
+                        out.append((e.name, dict(e.stats)))
+    return out
+
+
+def test_span_annotates_the_profiler_trace(tmp_path):
+    """With jax imported, a span is also a profiler annotation named by its
+    path, carrying its keyword arguments; the histogram is unchanged."""
+    ck = FakeClock()
+    reg = MetricsRegistry(clock=ck)
+    jax.profiler.start_trace(str(tmp_path))
+    with span("serve/dispatch", reg, batch=7):
+        ck.advance(0.5)
+        with span("session/call", reg):
+            pass
+    jax.profiler.stop_trace()
+    evs = dict(_trace_events(tmp_path, "serve/"))
+    assert evs["serve/dispatch"]["batch"] == 7
+    assert "serve/dispatch/session/call" in evs
+    assert reg.histogram("serve/dispatch").sum == 0.5
+    assert reg.histogram("serve/dispatch/session/call").count == 1
+
+
+def test_obs_imports_without_jax():
+    """obs never imports jax: a span opens no annotation where jax is not
+    loaded."""
+    import os
+    import subprocess
+    import sys
+    code = ("import sys; from repro.obs import MetricsRegistry, span\n"
+            "with span('serve/pack', MetricsRegistry(), batch=1) as s:\n"
+            "    assert s._ann is None\n"
+            "assert 'jax' not in sys.modules\n")
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+        [os.path.join(os.path.dirname(__file__), "..", "src"),
+         os.environ.get("PYTHONPATH", "")]))
+    subprocess.run([sys.executable, "-c", code], check=True, env=env,
+                   timeout=60)
+
+
 def test_registry_thread_safety_counters():
     reg = MetricsRegistry()
     c = reg.counter("n")
@@ -324,6 +378,47 @@ def test_zero_overhead_invariant(world):
     assert s2.compile_count == compiles_direct
     for req, want in zip(reqs, direct_logits):
         np.testing.assert_array_equal(req.logits, want)
+
+
+def test_engine_spans_of_a_batch_share_its_number(world, tmp_path):
+    """The engine's spans of one batch carry its sequence number into the
+    trace: pack, dispatch and answer of each of the two batches."""
+    layout, clouds = world
+    session = compile_network(_tiny_net(), layout, batch=4, min_bucket=128)
+    eng = PointCloudServeEngine(session, max_batch=2)
+    warm = [PointCloudRequest(c, f) for c, f in clouds[:2]]
+    eng.run(warm)                       # compile outside the trace
+    reqs = [PointCloudRequest(c, f) for c, f in clouds]
+    jax.profiler.start_trace(str(tmp_path))
+    eng.run(reqs)
+    jax.profiler.stop_trace()
+    assert all(r.outcome == "ok" for r in reqs)
+    by = {}
+    for name, stats in _trace_events(tmp_path, "serve/"):
+        by.setdefault(name, []).append(stats.get("batch"))
+    for name in ("serve/pack", "serve/dispatch", "serve/answer"):
+        assert sorted(by[name]) == [1, 2], name
+    assert by["serve/dispatch/session/call"] == [None, None]
+    hist = session.metrics.snapshot()["histograms"]
+    assert hist["serve/answer"]["count"] == 2 + 1
+
+
+def test_session_counts_the_rows_its_os_convs_walk(world):
+    """``spconv_rows_walked`` counts each OS conv's map rows (the bucket),
+    ``spconv_rows_real`` those that hold a voxel of its output level; the
+    WS layer counts in neither, and the plan alone records no span."""
+    layout, clouds = world
+    session = compile_network(_tiny_net(), layout, batch=4, min_bucket=128)
+    st = SparseTensor.from_point_clouds(clouds, session.layout)
+    session(st)
+    bucket = session.last_health.bucket
+    level1 = sum(len(np.unique(c >> 1, axis=0)) for c, _ in clouds)
+    snap = session.metrics.snapshot()["counters"]
+    assert snap["spconv_rows_walked"] == 2 * bucket      # l1 and l2
+    assert snap["spconv_rows_real"] == 2 * level1
+    session.plan(st)
+    assert not any(k.startswith("session/plan")
+                   for k in session.metrics.snapshot()["histograms"])
 
 
 def test_engine_counters_dict_api_compatible(world):
