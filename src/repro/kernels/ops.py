@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from . import ref as _ref
 from .masked_group_gemm import masked_group_gemm as _mgg_pallas
+from .spconv_gather_gemm import live_tiles
 from .spconv_gather_gemm import spconv_gather_gemm as _os_pallas
 from .ws_scatter_gemm import ws_scatter_gemm as _ws_pallas
 from .flash_attention import flash_attention as _fa_pallas
@@ -52,10 +53,14 @@ def resolve_backend(impl: str) -> Tuple[bool, bool]:
     return tpu, False
 
 
-def _row_tile(M: int, bm: int) -> Tuple[int, int]:
-    """(tile, padded_M). 0 → auto: 128-row tiles, M padded up."""
+def _tiled_map(m: jax.Array, bm: int) -> Tuple[jax.Array, int]:
+    """(``m`` padded with ``-1`` rows to whole row tiles, the row tile).
+    bm 0 → auto: 128-row tiles."""
     bm = bm or 128
-    return bm, ((M + bm - 1) // bm) * bm
+    pad = -m.shape[0] % bm
+    if pad:
+        m = jnp.pad(m, ((0, pad), (0, 0)), constant_values=-1)
+    return m, bm
 
 
 def _col_tile(Cout: int, bn: int) -> int:
@@ -75,13 +80,19 @@ def spconv_os_fused(features: jax.Array, m: jax.Array, weights: jax.Array,
         gathered = features[jnp.clip(m, 0)]
         return _ref.masked_group_gemm_ref(m, gathered, weights)
     M = m.shape[0]
-    bm, Mp = _row_tile(M, bm)
+    m, bm = _tiled_map(m, bm)
     bn = _col_tile(weights.shape[-1], bn)
-    if Mp != M:
-        m = jnp.pad(m, ((0, Mp - M), (0, 0)), constant_values=-1)
     out = _os_pallas(features, m, weights, bm=bm, bn=bn,
                      interpret=interpret or interp)
-    return out[:M] if Mp != M else out
+    return out[:M] if m.shape[0] != M else out
+
+
+def spconv_os_tiles(m: jax.Array, *, bm: int = 0) -> Tuple[int, jax.Array]:
+    """(walked, live): the row tiles the OS kernel of
+    :func:`spconv_os_fused` walks over ``m`` (static), and how many of
+    them hold a valid entry (an int32 scalar); it skips the others."""
+    m, bm = _tiled_map(m, bm)
+    return m.shape[0] // bm, live_tiles(m, bm).sum()
 
 
 def spconv_ws_fused(features: jax.Array, m: jax.Array, weights: jax.Array,

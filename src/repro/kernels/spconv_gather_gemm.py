@@ -7,6 +7,7 @@ intermediate that the unfused path (XLA gather + ``masked_group_gemm``)
 writes to and re-reads from HBM.
 
   grid = (M/bm, Cout/bn, Kd)            — out tile revisited along Kd
+  live, held [M/bm] SMEM (prefetched)   — per row tile, see below
   m block   (1, bm)        SMEM         — kernel-map column k of row tile i,
                                           from the [Kd, M/bm, 1, bm] view
   F_in      [N, 1, Cin_p]  HBM (ANY)    — gathered row-by-row by async copy
@@ -27,6 +28,25 @@ entry (m < 0) — the mask is applied in-register at gather time, never in
 memory — then waits for all the copies before one MXU matmul accumulates
 into fp32 scratch, flushed on the last offset. All copies of a tile share
 one DMA semaphore, so they are in flight together rather than one by one.
+
+Dead row tiles. A row tile whose map holds no valid entry at any offset is
+*dead*; the wrapper finds them from the map alone (:func:`live_tiles`) and
+prefetches two int32 ``[M/bm]`` arrays into SMEM before the grid starts
+(``PrefetchScalarGridSpec``): ``live`` (1 for a live tile) and ``held``
+(:func:`held_tiles`: the tile itself when live, else the last live tile
+before it). On a dead tile's steps the kernel skips the DMA walk, the
+waits and the matmul, and the index maps hold the map and weight blocks at
+those of the last step before the tile (offset Kd-1 and the last Cout tile
+of row tile ``held[i]``), so the pipeline copies nothing in; only the
+output tile is written back. The ``k == 0`` zero init and the
+``k == Kd-1`` flush still run, so a dead tile writes zeros — exactly the
+``0 @ W`` sums it computed before — and every output row stays
+bit-identical. The kernel maps of this package have dead tiles wherever
+rows are padding: ``zdelta_search`` writes -1 to every output row past the
+voxel count (the PAD tail of a capacity bucket), and
+``kernel_map.transpose_kernel_map`` to every input row no output reads, so
+the backward's dF_in pass skips them too. Nothing assumes the dead tiles
+form a tail: a dead tile between live ones is skipped the same way.
 
 HBM traffic vs the unfused path: the ``2·M·Kd·Cin`` intermediate bytes
 (write + re-read) disappear, and gather reads drop from ``M·Kd·Cin`` to
@@ -80,9 +100,23 @@ def row_copy(f_hbm, dst, idx, sem, n_in: int):
                                  sem)
 
 
-def _kernel(m_ref, f_hbm, w_ref, o_ref, acc_ref, g_ref, sem,
-            *, n_k, n_in, bm, cin):
-    k = pl.program_id(2)
+def live_tiles(m: jax.Array, bm: int) -> jax.Array:
+    """int32 ``[M/bm]``: 1 where the row tile holds a valid entry at some
+    offset, 0 for a dead tile (all ``-1``). Read from the tiled view the
+    kernel consumes, so XLA can fuse the reduction with its transpose."""
+    return (tile_columns(m, bm).max(axis=(0, 2, 3)) >= 0).astype(jnp.int32)
+
+
+def held_tiles(live: jax.Array) -> jax.Array:
+    """int32 ``[M/bm]``: the row tile whose map block a step reads — the
+    tile itself when live, else the last live tile before it (0 if none)."""
+    idx = jnp.arange(live.shape[0], dtype=jnp.int32)
+    return jax.lax.cummax(jnp.where(live != 0, idx, 0))
+
+
+def _kernel(live_ref, held_ref, m_ref, f_hbm, w_ref, o_ref, acc_ref, g_ref,
+            sem, *, n_k, n_in, bm, cin):
+    i, k = pl.program_id(0), pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
@@ -110,10 +144,13 @@ def _kernel(m_ref, f_hbm, w_ref, o_ref, acc_ref, g_ref, sem,
 
         return carry
 
-    jax.lax.fori_loop(0, bm, start, 0)
-    jax.lax.fori_loop(0, bm, wait, 0)
-    g = g_ref[...].reshape(bm, g_ref.shape[-1])[:, :cin]
-    acc_ref[...] += jnp.dot(g, w_ref[0], preferred_element_type=jnp.float32)
+    @pl.when(live_ref[i] != 0)
+    def _accumulate():
+        jax.lax.fori_loop(0, bm, start, 0)
+        jax.lax.fori_loop(0, bm, wait, 0)
+        g = g_ref[...].reshape(bm, g_ref.shape[-1])[:, :cin]
+        acc_ref[...] += jnp.dot(g, w_ref[0],
+                                preferred_element_type=jnp.float32)
 
     @pl.when(k == n_k - 1)
     def _flush():
@@ -136,23 +173,37 @@ def spconv_gather_gemm(
     Cout = weights.shape[-1]
     assert M % bm == 0 and Cout % bn == 0, (M, bm, Cout, bn)
     f3 = lane_rows(features)
-    grid = (M // bm, Cout // bn, Kd)
+    live = live_tiles(m, bm)
+    n_j = Cout // bn
+
+    # A dead tile's steps hold the blocks of the last step before them
+    # (offset Kd-1 and the last Cout tile of the held row tile), so the
+    # pipeline copies no map or weight block for them.
+    def m_block(i, j, k, live, held):
+        return jnp.where(live[i] != 0, k, Kd - 1), held[i], 0, 0
+
+    def w_block(i, j, k, live, held):
+        on = live[i] != 0
+        return jnp.where(on, k, Kd - 1), 0, jnp.where(on, j, n_j - 1)
+
     return pl.pallas_call(
         functools.partial(_kernel, n_k=Kd, n_in=N, bm=bm, cin=Cin),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((None, None, 1, bm), lambda i, j, k: (k, i, 0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec((1, Cin, bn), lambda i, j, k: (k, 0, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(M // bm, n_j, Kd),
+            in_specs=[
+                pl.BlockSpec((None, None, 1, bm), m_block,
+                             memory_space=pltpu.SMEM),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec((1, Cin, bn), w_block),
+            ],
+            out_specs=pl.BlockSpec((bm, bn), lambda i, j, k, *_: (i, j)),
+            scratch_shapes=[
+                pltpu.VMEM((bm, bn), jnp.float32),
+                pltpu.VMEM((bm,) + f3.shape[1:], features.dtype),
+                pltpu.SemaphoreType.DMA,
+            ]),
         out_shape=jax.ShapeDtypeStruct((M, Cout), features.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bm, bn), jnp.float32),
-            pltpu.VMEM((bm,) + f3.shape[1:], features.dtype),
-            pltpu.SemaphoreType.DMA,
-        ],
         interpret=interpret,
         name="spconv_gather_gemm",
-    )(tile_columns(m, bm), f3, weights)
+    )(live, held_tiles(live), tile_columns(m, bm), f3, weights)

@@ -221,9 +221,10 @@ class SpiraSession:
                 if cols.size == 0:
                     continue
             lossy.append((s.name, int(s.ws_capacity), cols))
-        # Rows of each OS conv's kernel map (its output capacity) per input
-        # capacity: static shapes, noted when ``run`` traces for a capacity.
-        os_rows: Dict[int, Dict[str, Tuple[int, int]]] = {}
+        # Rows of each OS conv's kernel map (its output capacity) and the
+        # row tiles its kernel walks, per input capacity: static shapes,
+        # noted when ``run`` traces for a capacity.
+        os_rows: Dict[int, Dict[str, Tuple[int, int, int]]] = {}
         self._os_rows[esc] = os_rows
 
         @jax.jit
@@ -233,9 +234,6 @@ class SpiraSession:
                                       downsample_method=method)
             logits = pointcloud_forward(params, net, plan, feats,
                                         layout=layout, segment=seg_spec)
-            os_rows[packed.shape[0]] = {
-                s.name: (s.m_out, plan.kmaps[s.name].m.shape[0])
-                for s in specs if s.dataflow == "os"}
             with jax.named_scope("outputs"):
                 out = plan.coords[out_level]
                 # Degradation signals, computed from the plan the call
@@ -249,7 +247,16 @@ class SpiraSession:
                     drops[name] = jnp.maximum(pairs - cap, 0).sum() \
                                      .astype(jnp.int32)
                 counts = {m: cs.count for m, cs in plan.coords.items()}
-            return logits, out.packed, out.count, drops, plan.stats, counts
+                tiles = {s.name: ops.spconv_os_tiles(plan.kmaps[s.name].m,
+                                                     bm=s.bm)
+                         for s in specs if s.dataflow == "os"}
+                live = sum(n for _, n in tiles.values())
+            os_rows[packed.shape[0]] = {
+                s.name: (s.m_out, plan.kmaps[s.name].m.shape[0],
+                         tiles[s.name][0])
+                for s in specs if s.dataflow == "os"}
+            return (logits, out.packed, out.count, drops, plan.stats,
+                    counts, live)
 
         self._fns[esc] = run
         return run
@@ -293,18 +300,19 @@ class SpiraSession:
             fn = self._make_fn(esc)
             # Span at the host boundary around the fused plan+forward call
             # and the one fetch of its plan-side scalars (WS drops, window
-            # overflows, each level's voxel count). Those depend on the
-            # plan alone, but a call's outputs all become ready when it
-            # ends, so the fetch waits for the forward too; fetching and
-            # unpacking the logits is the reader's (the serve engine's
-            # serve/answer span). Escalated retries record separately as
-            # session/replan.
+            # overflows, each level's voxel count, the OS convs' live row
+            # tiles). Those depend on the plan alone, but a call's outputs
+            # all become ready when it ends, so the fetch waits for the
+            # forward too; fetching and unpacking the logits is the
+            # reader's (the serve engine's serve/answer span). Escalated
+            # retries record separately as session/replan.
             with span("session/call" if esc == 0 else "session/replan",
                       self.metrics):
-                logits, out_packed, out_count, drops, ovf, counts = fn(
+                logits, out_packed, out_count, drops, ovf, counts, live = fn(
                     self.params, stp.packed, stp.features)
-                dropped, ovf, counts = jax.device_get((drops, ovf, counts))
-            self._count_rows(self._os_rows[esc][bucket], counts)
+                dropped, ovf, counts, live = jax.device_get(
+                    (drops, ovf, counts, live))
+            self._count_rows(self._os_rows[esc][bucket], counts, live)
             if sum(dropped.values()) == 0 or esc >= budget:
                 break
             esc += 1
@@ -321,18 +329,23 @@ class SpiraSession:
                            count=out_count, layout=self.layout)
         return out, health
 
-    def _count_rows(self, os_rows: Dict[str, Tuple[int, int]],
-                    counts: Mapping[int, int]) -> None:
+    def _count_rows(self, os_rows: Dict[str, Tuple[int, int, int]],
+                    counts: Mapping[int, int], live: int) -> None:
         """Rows the OS convs of one call walk (their maps' rows, the output
         capacity), and of those the rows that hold a voxel of the output
         level: ``spconv_rows_walked`` less ``spconv_rows_real`` is the
-        padding the OS kernel walks."""
-        walked = real = 0
-        for m_out, rows in os_rows.values():
+        padding the OS kernel walks. Likewise its row tiles:
+        ``spconv_tiles_walked`` less ``spconv_tiles_live`` are the dead
+        tiles whose work the kernel skips."""
+        walked = real = tiles = 0
+        for m_out, rows, n_tiles in os_rows.values():
             walked += rows
             real += min(int(counts[m_out]), rows)
+            tiles += n_tiles
         self.metrics.counter("spconv_rows_walked").inc(walked)
         self.metrics.counter("spconv_rows_real").inc(real)
+        self.metrics.counter("spconv_tiles_walked").inc(tiles)
+        self.metrics.counter("spconv_tiles_live").inc(int(live))
 
     def _record_health(self, health: HealthReport) -> None:
         """Fold one call's HealthReport into the registry: run/replan

@@ -78,6 +78,59 @@ def test_dispatch_pads_untiled_rows():
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+def _with_dead_tiles(m, dead):
+    """``m`` with the rows of ``dead`` (a slice) set to -1."""
+    m = np.array(m)
+    m[dead] = -1
+    return jnp.asarray(m)
+
+
+# Row tiles with no valid entry at any offset are skipped; what they write
+# (zeros) must match what the XLA dataflow computes for them, bit for bit.
+@pytest.mark.parametrize("M,Cout", [(512, 256), (328, 24)])
+@pytest.mark.parametrize("dead", [
+    pytest.param(slice(200, None), id="pad_tail"),
+    pytest.param(slice(128, 256), id="dead_between_live"),
+    pytest.param(slice(None), id="all_dead"),
+    pytest.param(slice(0, 0), id="none_dead"),
+])
+def test_gather_gemm_dead_tiles_bitmatch(M, Cout, dead):
+    rng = np.random.default_rng(4)
+    N, Cin = 300, 16
+    m = _with_dead_tiles(_rand_map(rng, M, 27, N), dead)
+    f = jnp.asarray(rng.normal(size=(N, Cin)).astype(np.float32))
+    w = jnp.asarray(rng.normal(size=(27, Cin, Cout)).astype(np.float32))
+    got = ops.spconv_os_fused(f, m, w, impl="pallas", bn=min(Cout, 128))
+    want = output_stationary(f, m, w)
+    assert got.shape == (M, Cout)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    if dead == slice(None):
+        assert not np.asarray(got).any()
+
+
+def test_gather_gemm_backward_pad_tail_bitmatch():
+    """dF_in runs the kernel over the transposed map, whose rows past the
+    inputs any output reads are -1: Pallas and XLA agree bit for bit."""
+    rng = np.random.default_rng(5)
+    M, n_out, N, n_read, Cin, Cout = 256, 180, 512, 200, 8, 16
+    m = np.full((M, 27), -1, np.int32)
+    for k in range(27):               # per-column injective, PAD tail
+        col = rng.permutation(n_read)[:n_out]
+        m[:n_out, k] = np.where(rng.random(n_out) < 0.3, col, -1)
+    m = jnp.asarray(m)
+    f = jnp.asarray(rng.normal(size=(N, Cin)).astype(np.float32))
+    w = jnp.asarray(rng.normal(size=(27, Cin, Cout)).astype(np.float32))
+    ct = jnp.asarray(rng.normal(size=(M, Cout)).astype(np.float32))
+
+    def df(backend):
+        return jax.grad(lambda f: (output_stationary(
+            f, m, w, backend=backend) * ct).sum())(f)
+
+    got, want = df("pallas"), df("xla")
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert not np.asarray(got)[256:].any()      # two dead row tiles
+
+
 # ---------------------------------------------------------------------------
 # dataflow dispatch + hybrid parity on real kernel maps (strides 1 and 2)
 # ---------------------------------------------------------------------------

@@ -421,6 +421,40 @@ def test_session_counts_the_rows_its_os_convs_walk(world):
                    for k in session.metrics.snapshot()["histograms"])
 
 
+def test_session_counts_the_row_tiles_its_os_convs_skip(world):
+    """``spconv_tiles_walked`` counts each OS conv's 128-row tiles,
+    ``spconv_tiles_live`` those the kernel does not skip: the tiles that
+    hold a voxel of the output level, ahead of its PAD tail."""
+    layout, clouds = world
+    session = compile_network(_tiny_net(), layout, batch=4, min_bucket=128)
+    session(SparseTensor.from_point_clouds(clouds, session.layout))
+    bucket = session.last_health.bucket
+    level1 = sum(len(np.unique(c >> 1, axis=0)) for c, _ in clouds)
+    snap = session.metrics.snapshot()["counters"]
+    assert snap["spconv_tiles_walked"] == 2 * bucket // 128   # l1 and l2
+    assert snap["spconv_tiles_live"] == 2 * math.ceil(level1 / 128)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_live_tiles_match_numpy(seed):
+    """A row tile is live iff some entry of its rows, at any offset, is a
+    valid input index."""
+    from repro.kernels.spconv_gather_gemm import live_tiles
+    rng = np.random.default_rng(seed)
+    bm, n_tiles, kd = 8, 24, 5
+    m = rng.integers(0, 50, (n_tiles * bm, kd)).astype(np.int32)
+    m[rng.random(m.shape) < 0.97] = -1
+    m[bm * rng.choice(n_tiles, 6, replace=False)[:, None]
+      + np.arange(bm)] = -1                     # whole tiles dead
+    m[:bm] = -1
+    m[bm - 1, kd - 1] = 0                       # input row 0 keeps it live
+    want = (m.reshape(n_tiles, bm * kd) >= 0).any(axis=1).astype(np.int32)
+    got = np.asarray(live_tiles(m, bm))
+    assert got.dtype == np.int32
+    np.testing.assert_array_equal(got, want)
+    assert 0 < want.sum() < n_tiles
+
+
 def test_engine_counters_dict_api_compatible(world):
     """The plain-int counter attributes and the counters dict keep their
     pre-registry surface while sourcing from the shared registry."""
