@@ -6,17 +6,18 @@
 
 For each seed, in one process and at the cell's own size, it runs the
 timed path as a run does (the same session, engine or trainer, with the
-seed's weights and traffic) and reads each number ``correct`` compares,
-against the reference at the configuration's matmul precision. For the
-first ``--control`` seeds it also reads the program against the reference
-at the highest precision; the control, the reference in bfloat16 put in
-the program's place; and a fault: for serving, an answer altered where it
-is produced (one voxel's logits replaced by another's), for training,
-half the batch
-left out (the reference with one scan of each batch unlabelled). A step
-that leaves the state unchanged reads 1 by the leaf measure and needs no
-run. One JSON line per seed goes to standard
-output and to ``--out``.
+seed's weights and traffic, its products at the configuration's matmul
+precision) and reads each number ``correct`` compares, against the
+reference at that precision. For the first ``--control`` seeds it also
+reads the program against the reference at the highest precision; the
+control (:func:`control`), the reference put in the program's place in
+the nearest precision below the stated one, and beside it the reference in
+bfloat16; and faults: for serving, an answer altered where it is produced
+(the first voxel's logits replaced by its neighbour's in the output order,
+and moved one class along), for training, half the batch left out (the
+reference with one scan of each batch unlabelled). A step that leaves the
+state unchanged reads 1 by the leaf measure and needs no run. One JSON
+line per seed goes to standard output and to ``--out``.
 """
 import time
 
@@ -62,8 +63,8 @@ def serve_seeds(cell, seeds, n_control, emit):
             row["program_at_highest"] = serve.check_sample(
                 c, served, params, hosts, precision=highest)
             lvl = c.net.out_level
-            worst = {"control_bf16": {}, "control_bf16_at_highest": {},
-                     "fault_altered_answer": {}}
+            ctl_name, dtype, prec = control(c)
+            worst = {}
             for k, scan, req in served:
                 hs = hosts.of(k, scan)
                 ref = serve.reference_logits(c, hs, scan, params)
@@ -77,13 +78,41 @@ def serve_seeds(cell, seeds, n_control, emit):
                        "control_bf16_at_highest": reference.logit_gaps(
                            vox, low[:m], hs, top, lvl),
                        "fault_altered_answer": reference.logit_gaps(
-                           req.voxels, altered(req.logits), hs, ref, lvl)}
+                           req.voxels, altered(req.logits), hs, ref, lvl),
+                       "fault_wrong_class": reference.logit_gaps(
+                           req.voxels, wrong_class(req.logits), hs, ref,
+                           lvl)}
+                if ctl_name not in got:
+                    ctl = serve.reference_logits(c, hs, scan, params, dtype,
+                                                 prec)
+                    got[ctl_name] = reference.logit_gaps(vox, ctl[:m], hs,
+                                                         ref, lvl)
                 for name, gaps in got.items():
                     for g, v in gaps.items():
-                        worst[name][g] = max(worst[name].get(g, 0.0), v)
+                        w = worst.setdefault(name, {})
+                        w[g] = max(w.get(g, 0.0), v)
             for name, gaps in worst.items():
                 row[name] = {g: {"value": v} for g, v in gaps.items()}
         emit(row)
+
+
+def in_place(cell, served, params, hosts, dtype, precision) -> list:
+    """``served`` as the reference in ``dtype`` at ``precision`` would have
+    served it: each request's voxels and logits replaced by the reference's,
+    the reference put in the program's place. Each pool scan is run once."""
+    import types
+    from bench.modes import serve
+    lvl, answers, out = cell.net.out_level, {}, []
+    for k, scan, req in served:
+        if k not in answers:
+            hs = hosts.of(k, scan)
+            logits = serve.reference_logits(cell, hs, scan, params, dtype,
+                                            precision)
+            answers[k] = types.SimpleNamespace(
+                outcome="ok", voxels=hs.coords(lvl),
+                logits=logits[:hs.count(lvl)])
+        out.append((k, scan, answers[k]))
+    return out
 
 
 def altered(logits):
@@ -95,10 +124,34 @@ def altered(logits):
     return bad
 
 
+def wrong_class(logits):
+    """An answer altered where it is produced: the first voxel's logits
+    moved one class along, so that it answers another class. Another
+    voxel's logits (:func:`altered`) can lie within rounding of the right
+    ones at level 0, where sparse outdoor voxels see the same few
+    neighbours."""
+    import numpy as np
+    bad = np.array(logits, np.float32)
+    bad[0] = np.roll(bad[0], 1)
+    return bad
+
+
+def control(cell) -> tuple:
+    """``(row name, dtype, precision)`` of the control: the reference in
+    the nearest precision below the one the configuration states, three
+    bfloat16 passes for float32 at ``highest``, bfloat16 for other
+    float32."""
+    import jax
+    import jax.numpy as jnp
+    from bench.modes import common
+    if cell.config["matmul_precision"] == "highest":
+        return "control_high", jnp.float32, jax.lax.Precision.HIGH
+    return "control_bf16", jnp.bfloat16, common.precision(cell)
+
+
 def train_seeds(cell, seeds, n_control, emit):
     import dataclasses
     import jax
-    import jax.numpy as jnp
     from repro.train.optimizer import init_opt_state
     from repro.train.pointcloud import labeled_tensor
     from bench import traffic
@@ -129,10 +182,10 @@ def train_seeds(cell, seeds, n_control, emit):
             top = train.reference_steps(c, pool, seen["p0"], n,
                                         precision=jax.lax.Precision.HIGHEST)
             row["program_at_highest"] = train.readings(seen, top)
+            name, dtype, prec = control(c)
             low = train.reference_steps(c, pool, seen["p0"], n,
-                                        dtype=jnp.bfloat16)
-            row["control_bf16"] = train.readings(
-                train.as_seen(low, seen["p0"]), ref)
+                                        dtype=dtype, precision=prec)
+            row[name] = train.readings(train.as_seen(low, seen["p0"]), ref)
             half = train.reference_steps(c, pool, seen["p0"], n,
                                          drop_scans=(1,))
             row["fault_half_batch"] = train.readings(
@@ -150,21 +203,8 @@ def main(argv=None, *, root: Path = ROOT, need_chip: bool = True) -> int:
     for path in (str(root / "src"), str(root)):
         if path not in sys.path:
             sys.path.insert(0, path)
-    import jax
     from bench import harness
     seeds = [int(s) for s in args.seeds.split(",")]
-    bench, entry, workload, centry, config = harness.find_cell(
-        root, args.workload)
-    chips = int(entry["chips"])
-    if need_chip:
-        devs = harness.require_chips(chips)
-        harness.enable_compile_cache(root)
-    else:
-        devs = jax.devices()[:chips]
-    cell = harness.Cell(name=args.workload, workload=workload, config=config,
-                        chips=chips, seed=seeds[0], seconds=0,
-                        trace=False, root=root, t0=T0,
-                        net=harness.reference_net(root, centry, config))
     out = open(args.out, "a") if args.out else None
 
     def emit(row):
@@ -176,12 +216,16 @@ def main(argv=None, *, root: Path = ROOT, need_chip: bool = True) -> int:
             out.flush()
 
     try:
-        {"serve": serve_seeds, "train": train_seeds}[workload["mode"]](
-            cell, seeds, args.control, emit)
+        with harness.open_cell(root, args.workload, seed=seeds[0],
+                               seconds=0, trace=False, t0=T0,
+                               need_chip=need_chip) as (_, cell, devs):
+            {"serve": serve_seeds, "train": train_seeds}[
+                cell.workload["mode"]](cell, seeds, args.control, emit)
+            print(json.dumps({"device": harness.device_record(devs)}),
+                  flush=True)
     finally:
         if out:
             out.close()
-    print(json.dumps({"device": harness.device_record(devs)}), flush=True)
     return 0
 
 

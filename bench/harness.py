@@ -14,6 +14,7 @@ adding files and ``BENCHMARK.json`` entries.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import gc
 import importlib
@@ -155,29 +156,75 @@ def read_per_layer(bench: dict, cell: str, ctx: dict, root: Path) -> Dict:
     return out
 
 
-def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
-        root: Path, t0: float, need_chip: bool = True) -> int:
-    """One run; the exit code. ``need_chip=False`` (tests on the CPU) skips
-    the look for a chip, and with it the persistent compilation cache, a
-    setting of the whole process."""
-    bench, entry, workload, centry, config = find_cell(root, cell_name)
+@contextlib.contextmanager
+def open_cell(root: Path, name: str, *, seed: int, seconds: float,
+              trace: bool, t0: float, need_chip: bool = True):
+    """``(BENCHMARK.json, the cell, its devices)`` for the block, with the
+    program's float32 products at the matmul precision its configuration
+    states (:func:`matmul_precision`). Every way of driving a cell (a run,
+    a calibration, the scope report) opens it here. Raises :class:`NoChip`
+    before anything is built when JAX holds too few chips;
+    ``need_chip=False`` (tests on the CPU) skips that look, and with it the
+    persistent compilation cache, a setting of the whole process."""
+    bench, entry, workload, centry, config = find_cell(root, name)
     chips = int(entry["chips"])
     import jax
+    if need_chip:
+        devs = require_chips(chips)
+        enable_compile_cache(root)
+    else:
+        devs = jax.devices()[:chips]
+    cell = Cell(name=name, workload=workload, config=config, chips=chips,
+                seed=seed, seconds=seconds, trace=trace, root=root, t0=t0,
+                on_chip=need_chip, net=reference_net(root, centry, config))
+    with matmul_precision(config["matmul_precision"]):
+        yield bench, cell, devs
+
+
+@contextlib.contextmanager
+def matmul_precision(name: str):
+    """Run the block with the program's float32 products at matmul
+    precision ``name``, in every thread of the process (the serving engine
+    may call the session from a worker thread), and restore JAX's setting
+    afterwards.
+
+    ``default`` leaves JAX's setting as it is, so the program compiles
+    as it would with nothing asked for. ``highest`` sets it for the whole
+    process: every product that names no precision, the Pallas kernels'
+    among them, computes in full float32. ``high`` is refused before
+    anything is built: the Pallas TPU lowering has no three-pass bfloat16
+    mode, so the program cannot run at it."""
+    import jax
+    from bench import reference
+    reference.precision(name)
+    if name == "high":
+        raise ValueError("matmul_precision 'high': the program's Pallas "
+                         "kernels have no three-pass bfloat16 mode on the "
+                         "TPU; state 'default' or 'highest'")
+    if name == "default":
+        yield
+        return
+    before = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_default_matmul_precision", name)
     try:
-        if need_chip:
-            devs = require_chips(chips)
-            enable_compile_cache(root)
-        else:
-            devs = jax.devices()[:chips]
+        yield
+    finally:
+        jax.config.update("jax_default_matmul_precision", before)
+
+
+def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
+        root: Path, t0: float, need_chip: bool = True) -> int:
+    """One run; the exit code."""
+    try:
+        with open_cell(root, cell_name, seed=seed, seconds=seconds,
+                       trace=trace, t0=t0, need_chip=need_chip) as (
+                           bench, cell, devs):
+            mode = importlib.import_module(
+                f"bench.modes.{cell.workload['mode']}")
+            res = mode.run(cell, devs)
     except NoChip as e:
         print(f"bench: {e}; nothing is run off the chip", file=sys.stderr)
         return 3
-    cell = Cell(name=cell_name, workload=workload, config=config,
-                chips=chips, seed=seed, seconds=seconds, trace=trace,
-                root=root, t0=t0, on_chip=need_chip,
-                net=reference_net(root, centry, config))
-    mode = importlib.import_module(f"bench.modes.{workload['mode']}")
-    res = mode.run(cell, devs)
 
     metrics = {}
     if trace:

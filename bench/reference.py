@@ -3,7 +3,9 @@
 It computes in float32 at the matmul precision that the configuration
 states (``matmul_precision``, :func:`precision`): ``default`` is what JAX
 gives a float32 product when nothing is asked for, which on a TPU is one
-bfloat16 pass with float32 sums, and on the CPU full float32.
+bfloat16 pass with float32 sums, and on the CPU full float32; ``highest``
+is full float32 everywhere; ``high`` is three bfloat16 passes, written out
+(:func:`_dot`), which only a control asks for.
 
 Everything here is written from the networks' mathematics, with none of
 the engine's machinery: no packed words, no z-delta search, no capacity
@@ -225,12 +227,30 @@ def device_inputs(scans: Sequence[HostScan], feats: Sequence[np.ndarray],
 # device: the forward pass, the loss, the optimizer
 # ---------------------------------------------------------------------------
 
+def _dot(a, b, precision):
+    """``a @ b`` with float32 sums at ``precision``. ``HIGH`` on float32
+    is written out as the three bfloat16 products a TPU computes for it
+    (each operand split into a bfloat16 high part and a bfloat16 rest; the
+    two rests' product left out), so that it means the same on every
+    platform."""
+    if precision == jax.lax.Precision.HIGH and a.dtype == jnp.float32:
+        def split(v):
+            hi = v.astype(jnp.bfloat16)
+            return hi, (v - hi.astype(v.dtype)).astype(jnp.bfloat16)
+
+        (ah, al), (bh, bl) = split(a), split(b)
+        d = partial(jnp.dot, precision=jax.lax.Precision.DEFAULT,
+                    preferred_element_type=jnp.float32)
+        return d(ah, bh) + (d(ah, bl) + d(al, bh))
+    return jnp.dot(a, b, precision=precision,
+                   preferred_element_type=jnp.float32)
+
+
 def _conv(x, nbr, w, b, count, precision):
     def body(acc, col_w):
         col, wk = col_w
         g = jnp.where((col >= 0)[:, None], x[jnp.maximum(col, 0)], 0)
-        return acc + jnp.dot(g, wk, precision=precision,
-                             preferred_element_type=jnp.float32), None
+        return acc + _dot(g, wk, precision), None
 
     acc0 = jnp.zeros((nbr.shape[0], w.shape[-1]), jnp.float32)
     acc, _ = jax.lax.scan(jax.checkpoint(body), acc0, (nbr.T, w))
@@ -259,8 +279,7 @@ def forward_one(params, net: Net, x, maps, counts, precision):
         x = _relu_bn(y, counts[str(L.m_out)])
         if L.skip_out is not None:
             skips[L.skip_out] = x
-    return jnp.dot(x, params["head"], precision=precision,
-                   preferred_element_type=jnp.float32).astype(x.dtype)
+    return _dot(x, params["head"], precision).astype(x.dtype)
 
 
 def _cast(tree, dtype):
@@ -361,9 +380,14 @@ def logit_gaps(served_voxels: np.ndarray, served: np.ndarray,
                ) -> Dict[str, float]:
     """|served - reference| over the scan's largest reference logit: the
     largest (``logit_gap``), the root mean square (``logit_rms_gap``) and
-    the median (``logit_median_gap``) over every logit of the scan. The
-    served rows are matched to the reference's by their voxel; a missing,
-    extra or repeated voxel reads infinity."""
+    the median (``logit_median_gap``) over every logit of the scan; and
+    the largest over voxels of |served - reference| over the range (largest
+    less smallest) of the voxel's own reference logits
+    (``logit_voxel_gap``), which an answer moved one class along reads at
+    2/C or more of, for C classes, however small the voxel's logits lie
+    beside the scan's largest. The served rows are matched to the
+    reference's by their voxel; a missing, extra or repeated voxel reads
+    infinity."""
     bad = {k: math.inf for k in GAPS}
     keys = scan.keys[level]
     if served.shape[0] != len(keys) or served_voxels.shape[0] != len(keys):
@@ -376,13 +400,18 @@ def logit_gaps(served_voxels: np.ndarray, served: np.ndarray,
     have = np.asarray(served, np.float64)[order]
     if not np.isfinite(have).all():
         return bad
-    d = np.abs(have - want) / max(np.abs(want).max(), 1e-30)
+    diff = np.abs(have - want)
+    d = diff / max(np.abs(want).max(), 1e-30)
+    spread = np.ptp(want, axis=1)
     return {"logit_gap": float(d.max()),
             "logit_rms_gap": float(np.sqrt(np.mean(np.square(d)))),
-            "logit_median_gap": float(np.median(d))}
+            "logit_median_gap": float(np.median(d)),
+            "logit_voxel_gap": float(
+                (diff.max(axis=1) / np.maximum(spread, 1e-30)).max())}
 
 
-GAPS = ("logit_gap", "logit_rms_gap", "logit_median_gap")
+GAPS = ("logit_gap", "logit_rms_gap", "logit_median_gap",
+        "logit_voxel_gap")
 
 
 def leaf_norm_gaps(have: dict, want: dict, skip: Sequence[str] = ()

@@ -313,27 +313,19 @@ def main(argv=None, *, root: Path = ROOT, need_chip: bool = True) -> int:
     p.add_argument("--seconds", type=float, required=True)
     args = p.parse_args(argv)
     from bench import harness
-    _, entry, workload, centry, config = harness.find_cell(root,
-                                                           args.workload)
-    if workload["mode"] != "serve":
-        print(f"bench: {args.workload} is no serving cell", file=sys.stderr)
-        return 2
-    import jax
     try:
-        if need_chip:
-            devs = harness.require_chips(int(entry["chips"]))
-            harness.enable_compile_cache(root)
-        else:
-            devs = jax.devices()[:1]
+        with harness.open_cell(root, args.workload, seed=args.seed,
+                               seconds=args.seconds, trace=True, t0=T0,
+                               need_chip=need_chip) as (_, cell, devs):
+            if cell.workload["mode"] != "serve":
+                print(f"bench: {args.workload} is no serving cell",
+                      file=sys.stderr)
+                return 2
+            out = report(cell, devs)
     except harness.NoChip as e:
         print(f"bench: {e}", file=sys.stderr)
         return 3
-    cell = harness.Cell(name=args.workload, workload=workload,
-                        config=config, chips=int(entry["chips"]),
-                        seed=args.seed, seconds=args.seconds, trace=True,
-                        root=root, t0=T0, on_chip=need_chip,
-                        net=harness.reference_net(root, centry, config))
-    print(json.dumps(report(cell, devs)), flush=True)
+    print(json.dumps(out), flush=True)
     return 0
 
 

@@ -67,6 +67,28 @@ def test_reference_matches_the_session(config, extent, tol):
     assert gaps["logit_gap"] < tol
 
 
+def test_voxel_gap_reads_a_wrong_class_where_logits_are_small():
+    """One voxel's answer moved one class along, on a voxel whose logits
+    lie a thousandth of the scan's largest: the scan-wide gap barely moves,
+    the voxel's own gap reads 2/C of its range or more."""
+    scan = traffic.scan_batch(5, 1, "indoor", (32, 28, 16), 0.3)[0]
+    net = reference.Net((reference.Layer("a", 1, 1, 3, 0, 0),), 1, 19)
+    hs = reference.build_scan(scan.coords, net)
+    ref = np.random.default_rng(3).normal(size=(hs.count(0), 19))
+    ref[7] *= 1e-3
+    vox = hs.coords(0)
+    sound = reference.logit_gaps(vox, ref, hs, ref, 0)
+    assert sound["logit_gap"] == sound["logit_voxel_gap"] == 0.0
+    bad = ref.copy()
+    bad[7] = np.roll(bad[7], 1)
+    gaps = reference.logit_gaps(vox, bad, hs, ref, 0)
+    assert gaps["logit_gap"] < 2e-3
+    assert gaps["logit_voxel_gap"] >= 2 / 19
+    shuffled = np.random.default_rng(4).permutation(len(vox))
+    assert reference.logit_gaps(vox[shuffled], bad[shuffled], hs, ref,
+                                0) == gaps
+
+
 def test_reference_gradient_matches_the_program():
     """Loss and gradients of a batch of two labelled scans: the program's
     fused plan-forward-loss graph differentiated by JAX, against the

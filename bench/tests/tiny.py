@@ -48,11 +48,13 @@ def make_root(tmp: Path, cells=None) -> Path:
     test configuration ``tiny_segnet`` and the metric readers, and tiny
     cells (by default ``tiny.serve`` and ``tiny.train``). A tiny cell takes
     the limits and the optimizer of the real cell of its mode, where it
-    names none, and reports the metrics of the real cells of its mode; a
+    names none (of the real cell of its configuration, where there is
+    one), and reports the metrics of the real cells of its mode; a
     training cell reports ``train_voxels_per_s``."""
     bench = benchmark()
     real = real_workloads()
     by_mode = {w["mode"]: w for w in real.values()}
+    by_config = {w["config"]: w for w in real.values()}
     (tmp / "bench" / "workloads").mkdir(parents=True)
     for d in ("configs", "metrics"):
         shutil.copytree(BENCH / d, tmp / "bench" / d)
@@ -65,9 +67,10 @@ def make_root(tmp: Path, cells=None) -> Path:
     entries = []
     for name, wl in cells.items():
         wl = dict(wl)
+        like = by_config.get(wl["config"], by_mode.get(wl["mode"], {}))
         for key in ("limits", "optimizer"):
-            if key in by_mode.get(wl["mode"], {}):
-                wl.setdefault(key, by_mode[wl["mode"]][key])
+            if key in like:
+                wl.setdefault(key, like[key])
         (tmp / "bench" / "workloads" / f"{name}.json").write_text(
             json.dumps(wl))
         entries.append({"name": name, "config": wl["config"],
