@@ -9,8 +9,20 @@ checks what the window produced against the reference. The harness then
 reads the metrics the cell reports, each per-layer metric by its reader
 ``bench/metrics/<metric>.py``, and prints the result line.
 
+A configuration is its file of sizes, ``bench/configs/<name>.json``, and
+the reference file it names beside it, which defines ``net(cfg)``: the
+network's convs as the reference's layer table (``reference.Net``). The
+reference file may also define, in place of the shared functions
+(:data:`HOOKS`), ``init_params(key, net, dtype)``, ``device_inputs(scans,
+feats, net, labels=None)``, ``forward_one(params, net, inp, precision)``,
+``program_net(cfg, net)`` and ``forward_flops(scan, net)``: then the
+weights, the reference's arrays and logits, the program's network and the
+count of useful operations are its own. Every caller takes them through
+:meth:`Cell.hook`.
+
 Nothing here names a cell, a configuration or a metric: adding one is
-adding files and ``BENCHMARK.json`` entries.
+adding files and ``BENCHMARK.json`` entries, also for a network that is
+more than a stack of convs.
 """
 from __future__ import annotations
 
@@ -26,6 +38,14 @@ from pathlib import Path
 from typing import Dict, List
 
 CACHE_DIR = ".jax_cache"
+
+# What a configuration's reference file may define, and the module of the
+# shared function used where it does not.
+HOOKS = {"init_params": "bench.reference",
+         "device_inputs": "bench.reference",
+         "forward_one": "bench.reference",
+         "program_net": "bench.modes.common",
+         "forward_flops": "bench.opcount"}
 
 
 class NoChip(RuntimeError):
@@ -46,10 +66,19 @@ class Cell:
     t0: float                       # perf_counter at process start
     net: object = None              # the reference's description (Net)
     on_chip: bool = True            # False only in the tests on the CPU
+    hooks: object = None            # the configuration's reference file
 
     @property
     def traffic(self) -> dict:
         return self.workload["traffic"]
+
+    def hook(self, name: str):
+        """The configuration's reference file's function ``name`` where it
+        defines one, else the shared one (:data:`HOOKS`)."""
+        own = getattr(self.hooks, name, None)
+        if own is not None:
+            return own
+        return getattr(importlib.import_module(HOOKS[name]), name)
 
 
 def load_json(path: Path) -> dict:
@@ -85,11 +114,11 @@ def find_cell(root: Path, name: str) -> tuple:
     return bench, entry, workload, centry, config
 
 
-def reference_net(root: Path, centry: dict, config: dict):
-    """The configuration's layer table, from its reference file beside the
-    configuration file."""
+def reference_file(root: Path, centry: dict, config: dict):
+    """The configuration's reference file, beside its configuration file,
+    as a module."""
     path = (root / centry["file"]).parent / config["reference"]
-    return load_module(path, f"bench_config_{centry['name']}").net(config)
+    return load_module(path, f"bench_config_{centry['name']}")
 
 
 def reported(bench: dict, cell: str, trace: bool) -> List[dict]:
@@ -174,9 +203,10 @@ def open_cell(root: Path, name: str, *, seed: int, seconds: float,
         enable_compile_cache(root)
     else:
         devs = jax.devices()[:chips]
+    ref = reference_file(root, centry, config)
     cell = Cell(name=name, workload=workload, config=config, chips=chips,
                 seed=seed, seconds=seconds, trace=trace, root=root, t0=t0,
-                on_chip=need_chip, net=reference_net(root, centry, config))
+                on_chip=need_chip, net=ref.net(config), hooks=ref)
     with matmul_precision(config["matmul_precision"]):
         yield bench, cell, devs
 

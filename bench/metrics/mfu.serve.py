@@ -1,5 +1,6 @@
-"""Useful operations of the window's scans (2 nnz Cin Cout over the real
-voxels' kernel maps, and the classifier) over the traced window, as a
+"""Useful operations of the window's scans (the configuration's
+``forward_flops``; by default 2 nnz Cin Cout over the real voxels' kernel
+maps, and the classifier, bench/opcount.py) over the traced window, as a
 share of the chip's bf16 peak (bench/peaks.py)."""
 
 

@@ -16,11 +16,13 @@ from bench import harness, peaks, reference, tracing
 
 
 def make_params(cell: harness.Cell):
-    """The weights, made on the device from the seed in one jitted call."""
+    """The weights, made on the device from the seed in one jitted call of
+    the configuration's ``init_params``."""
     import jax
     import jax.numpy as jnp
     dtype = jnp.dtype(cell.config["dtype"])
-    init = jax.jit(partial(reference.init_params, net=cell.net, dtype=dtype))
+    init = jax.jit(partial(cell.hook("init_params"), net=cell.net,
+                           dtype=dtype))
     params = init(reference.seed_key(cell.seed))
     jax.block_until_ready(params)
     return params
@@ -31,15 +33,37 @@ def precision(cell: harness.Cell):
     return reference.precision(cell.config["matmul_precision"])
 
 
-def program_net(cell: harness.Cell):
-    """The program's network for the configuration: one ``SpConvSpec`` per
-    layer of the reference's description, at the program's defaults."""
+def program_net(cfg: dict, net):
+    """The program's network for configuration ``cfg``: one ``SpConvSpec``
+    per layer of the reference's description ``net``, at the program's
+    defaults."""
     from repro.core import SpConvSpec
     from repro.models.pointcloud import PointCloudNet
     specs = tuple(SpConvSpec(L.name, L.cin, L.cout, K=L.K, m_in=L.m_in,
-                             m_out=L.m_out) for L in cell.net.layers)
-    return PointCloudNet(cell.config["network"], specs,
-                         cell.net.in_channels, cell.net.n_classes)
+                             m_out=L.m_out) for L in net.layers)
+    return PointCloudNet(cfg["network"], specs, net.in_channels,
+                         net.n_classes)
+
+
+def checked_program_net(cell: harness.Cell):
+    """The configuration's ``program_net``, whose convs must be the
+    reference's layers: the program runs whatever weights it is given, so
+    a conv that differs would go unseen. Raises naming the first that
+    differs, before anything is compiled."""
+    net = cell.hook("program_net")(cell.config, cell.net)
+    have = {s.name: (s.cin, s.cout, s.K, s.m_in, s.m_out)
+            for s in net.conv_specs()}
+    for L in cell.net.layers:
+        want = (L.cin, L.cout, L.K, L.m_in, L.m_out)
+        got = have.pop(L.name, None)
+        if got != want:
+            raise ValueError(
+                f"the program's conv {L.name!r} (cin, cout, K, m_in, m_out) "
+                f"is {got}, the reference's layer {want}")
+    if have:
+        raise ValueError(f"the program's convs {sorted(have)} are no layers "
+                         "of the reference")
+    return net
 
 
 def one_per_bucket(session, sizes: Sequence[int]) -> List[int]:

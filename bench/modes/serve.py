@@ -1,7 +1,8 @@
 """Serving: scans through the program's serving engine, closed loop.
 
 Set-up makes the pool of scans and the weights from the seed, compiles the
-network with ``compile_network(net, layout, params=...)`` (the session's
+configuration's network (its ``program_net``, ``harness.Cell.hook``) with
+``compile_network(net, layout, params=...)`` (the session's
 defaults: one scan per call, the engine, backend and search that the
 session picks) and puts one scan through a ``PointCloudServeEngine`` to
 compile the bucket. The window keeps ``traffic["in_flight"]`` (one) scan
@@ -41,7 +42,7 @@ def build(cell: harness.Cell, params=None):
     feats = [traffic.scan_features(s, cell.net.in_channels) for s in pool]
     if params is None:
         params = common.make_params(cell)
-    net = common.program_net(cell)
+    net = common.checked_program_net(cell)
     layout = BitLayout.for_extent(*tr["extent"], guard=traffic.GUARD)
     session = compile_network(net, layout, params=params)
     engine = PointCloudServeEngine(session)
@@ -101,11 +102,12 @@ def reference_logits(cell, hs, scan, params_host, dtype=None,
     in float32 at the configuration's matmul precision."""
     import jax.numpy as jnp
     net = cell.net
-    inp = reference.device_inputs(
+    inp = cell.hook("device_inputs")(
         [hs], [traffic.scan_features(scan, net.in_channels)], net)
     return np.asarray(reference.forward(
         params_host, inp, net=net, dtype=dtype or jnp.float32,
-        precision=precision or common.precision(cell)))[0]
+        precision=precision or common.precision(cell),
+        forward_one=cell.hook("forward_one")))[0]
 
 
 def run(cell: harness.Cell, devs) -> dict:
@@ -149,9 +151,10 @@ def run(cell: harness.Cell, devs) -> dict:
     if cell.trace:
         summary = win.summary()
         work = {"forward_flops": 0.0, "os_flops": 0.0, "os_bytes": 0.0}
+        forward_flops = cell.hook("forward_flops")
         for k, scan, _ in served:
             hs = hosts.of(k, scan)
-            work["forward_flops"] += opcount.forward_flops(hs, cell.net)
+            work["forward_flops"] += forward_flops(hs, cell.net)
             w = opcount.os_call_work(hs, cell.net, backward=False)
             work["os_flops"] += w["flops"]
             work["os_bytes"] += w["bytes"]

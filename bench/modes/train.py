@@ -1,8 +1,9 @@
 """Training: steps of the program's trainer on labelled scans.
 
 Set-up makes the pool of batches (``traffic["scans_per_item"]`` labelled
-scans each) and the weights from the seed, compiles the network for that
-many scans per call, builds the trainer with ``session.compile_train()``
+scans each) and the weights from the seed, compiles the configuration's
+network (its ``program_net``, ``harness.Cell.hook``) for that many scans
+per call, builds the trainer with ``session.compile_train()``
 (the trainer's own AdamW) and drives it through its first ``check_steps``
 steps on batches that all differ: the first compiles, and the reference
 follows all of them. The window then goes on with the same trainer,
@@ -36,7 +37,7 @@ def build(cell: harness.Cell, params=None):
     pool = traffic.scan_pool(cell.seed, dict(tr, labels=True))
     if params is None:
         params = common.make_params(cell)
-    net = common.program_net(cell)
+    net = common.checked_program_net(cell)
     layout = BitLayout.for_extent(*tr["extent"], guard=traffic.GUARD)
     per = int(tr["scans_per_item"])
     session = compile_network(net, layout, params=params, batch=per)
@@ -128,11 +129,12 @@ def reference_steps(cell: harness.Cell, pool, p0, n: int, dtype=None,
         feats = [traffic.scan_features(s, net.in_channels) for s in item]
         labels = [s.labels if i not in drop_scans
                   else np.full_like(s.labels, -1) for i, s in enumerate(item)]
-        inputs.append(reference.device_inputs(hs, feats, net, labels))
+        inputs.append(cell.hook("device_inputs")(hs, feats, net, labels))
     return reference.train_steps(p0, inputs, net, optimizer(cell),
                                  dtype=dtype or jnp.float32,
                                  precision=precision
-                                 or common.precision(cell))
+                                 or common.precision(cell),
+                                 forward_one=cell.hook("forward_one"))
 
 
 def optimizer(cell: harness.Cell) -> reference.AdamW:
@@ -170,13 +172,14 @@ def run(cell: harness.Cell, devs) -> dict:
     if cell.trace:
         summary = win.summary()
         work = {"train_flops": 0.0, "os_flops": 0.0, "os_bytes": 0.0}
+        forward_flops = cell.hook("forward_flops")
         hosts = {}
         for k in steps:
             for i, s in enumerate(pool[k]):
                 if (k, i) not in hosts:
                     hosts[(k, i)] = reference.build_scan(s.coords, cell.net)
                 hs = hosts[(k, i)]
-                work["train_flops"] += 3 * opcount.forward_flops(hs, cell.net)
+                work["train_flops"] += 3 * forward_flops(hs, cell.net)
                 w = opcount.os_call_work(hs, cell.net, backward=True)
                 work["os_flops"] += w["flops"]
                 work["os_bytes"] += w["bytes"]

@@ -29,6 +29,11 @@ buckets shared with the program, no custom gradients. It decides
 * Training: masked mean cross-entropy over every labelled voxel of the
   batch, ``jax.grad``, and AdamW with global-norm clipping, written from
   the optimizer's equations.
+* A network that is more than such a stack: its configuration's reference
+  file defines its own ``init_params``, ``device_inputs`` or
+  ``forward_one`` (``bench.harness.Cell.hook``), and :func:`forward`,
+  :func:`loss_fn` and :func:`train_steps` run the ``forward_one`` they
+  are given.
 
 The device arrays are padded per level to a power of two (rows beyond the
 scan's voxels have no neighbours and are masked), so that every seed of a
@@ -267,8 +272,11 @@ def _relu_bn(y, count):
     return jnp.where(valid, (y - mean) * jax.lax.rsqrt(var + EPS_BN), 0)
 
 
-def forward_one(params, net: Net, x, maps, counts, precision):
-    """Logits of one scan, rows in key order of the output level."""
+def forward_one(params, net: Net, inp, precision):
+    """Logits of one scan, rows in key order of the output level. ``inp``
+    holds the scan's arrays of :func:`device_inputs` (``x``, ``maps``,
+    ``counts``), its floating ones in the computing type."""
+    x, maps, counts = inp["x"], inp["maps"], inp["counts"]
     skips = {}
     for L in net.layers:
         if L.skip_in is not None:
@@ -287,19 +295,23 @@ def _cast(tree, dtype):
                         if jnp.issubdtype(a.dtype, jnp.floating) else a, tree)
 
 
-@partial(jax.jit, static_argnames=("net", "dtype", "precision"))
+@partial(jax.jit, static_argnames=("net", "dtype", "precision",
+                                   "forward_one"))
 def forward(params, inp, *, net: Net, dtype=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST):
-    """Logits ``[B, cap_out, n_classes]`` of a stack of scans."""
+            precision=jax.lax.Precision.HIGHEST, forward_one=forward_one):
+    """Logits ``[B, cap_out, n_classes]`` of a stack of scans: each scan's
+    arrays of ``inp`` but its labels, through ``forward_one`` (a
+    configuration's own, or :func:`forward_one`)."""
     p = _cast(params, dtype)
-    x = inp["x"].astype(dtype)
-    f = lambda xi, mi, ci: forward_one(p, net, xi, mi, ci, precision)
-    return jax.vmap(f)(x, inp["maps"], inp["counts"]).astype(jnp.float32)
+    scans = _cast({k: v for k, v in inp.items() if k != "labels"}, dtype)
+    f = lambda one: forward_one(p, net, one, precision)
+    return jax.vmap(f)(scans).astype(jnp.float32)
 
 
-def loss_fn(params, inp, net: Net, dtype, precision):
+def loss_fn(params, inp, net: Net, dtype, precision, forward_one):
     """Masked mean cross-entropy over every labelled voxel of the batch."""
-    logits = forward(params, inp, net=net, dtype=dtype, precision=precision)
+    logits = forward(params, inp, net=net, dtype=dtype, precision=precision,
+                     forward_one=forward_one)
     lab = inp["labels"]
     valid = lab >= 0
     logp = jax.nn.log_softmax(logits, axis=-1)
@@ -332,15 +344,18 @@ class AdamW:
                                  + (1 - self.min_lr_ratio) * cos)
 
 
-@partial(jax.jit, static_argnames=("net", "dtype", "precision"))
+@partial(jax.jit, static_argnames=("net", "dtype", "precision",
+                                   "forward_one"))
 def loss_and_grad(params, inp, *, net: Net, dtype=jnp.float32,
-                  precision=jax.lax.Precision.HIGHEST):
-    return jax.value_and_grad(loss_fn)(params, inp, net, dtype, precision)
+                  precision=jax.lax.Precision.HIGHEST,
+                  forward_one=forward_one):
+    return jax.value_and_grad(loss_fn)(params, inp, net, dtype, precision,
+                                       forward_one)
 
 
 def train_steps(params, inputs: Sequence[dict], net: Net, opt: AdamW, *,
-                dtype=jnp.float32, precision=jax.lax.Precision.HIGHEST
-                ) -> dict:
+                dtype=jnp.float32, precision=jax.lax.Precision.HIGHEST,
+                forward_one=forward_one) -> dict:
     """Run ``len(inputs)`` AdamW steps from ``params`` (float32 state).
     Returns each step's loss, the first step's clipped gradient and the
     final parameters, all on the host."""
@@ -350,7 +365,7 @@ def train_steps(params, inputs: Sequence[dict], net: Net, opt: AdamW, *,
     losses, first_grad = [], None
     for t, inp in enumerate(inputs):
         loss, g = loss_and_grad(p, inp, net=net, dtype=dtype,
-                                precision=precision)
+                                precision=precision, forward_one=forward_one)
         g = jax.tree.map(lambda a: a.astype(jnp.float32), g)
         gnorm = math.sqrt(sum(float(jnp.sum(jnp.square(a)))
                               for a in jax.tree.leaves(g)))

@@ -1,5 +1,6 @@
 """The benchmark's files: the contract of BENCHMARK.json, discovery by
 name, and a cell added as files alone."""
+import importlib
 import json
 import re
 
@@ -47,10 +48,11 @@ def test_every_cell_finds_its_files(bench):
     layers = (ROOT / "PERF.md").read_text()
     for w in bench["workloads"]:
         _, entry, wl, centry, cfg = harness.find_cell(ROOT, w["name"])
-        net = harness.reference_net(ROOT, centry, cfg)
+        net = harness.reference_file(ROOT, centry, cfg).net(cfg)
         assert net.in_channels == cfg["in_channels"]
-        assert wl["mode"] in ("serve", "train")
         assert (ROOT / "bench" / "modes" / f"{wl['mode']}.py").exists()
+        assert callable(importlib.import_module(
+            f"bench.modes.{wl['mode']}").run)
         got = [m["name"] for m in harness.reported(bench, w["name"], False)]
         assert "setup_s" in got and len(got) >= 2, got
         per = harness.reported(bench, w["name"], True)
@@ -68,26 +70,22 @@ def test_every_cell_finds_its_files(bench):
 
 
 def test_program_networks_are_the_configured_ones(bench):
-    """Every configuration file, those of no cell too (minkunet42)."""
+    """Every configuration file, also one that no cell runs: the program's
+    convs are the reference's layers, as many as the file states."""
     from bench.modes import common
-    convs = {"minkunet42": 42, "sparse_resnet21": 20}
     files = sorted((ROOT / "bench" / "configs").glob("*.json"))
     assert {c["file"] for c in bench["configs"]} <= {
         str(f.relative_to(ROOT)) for f in files}
     for f in files:
-        cfg = harness.load_json(f)
-        centry = {"name": f.stem, "file": str(f.relative_to(ROOT))}
-        cell = harness.Cell(name=f.stem, workload={}, config=cfg,
-                            chips=1, seed=0, seconds=0, trace=False,
-                            root=ROOT, t0=0.0,
-                            net=harness.reference_net(ROOT, centry, cfg))
-        net = common.program_net(cell)
+        cell = tiny.config_cell(str(f.relative_to(ROOT)))
+        cfg = cell.config
+        net = common.checked_program_net(cell)
         have = [(s.name, s.cin, s.cout, s.K, s.m_in, s.m_out)
-                for s in net.specs]
+                for s in net.conv_specs()]
         want = [(L.name, L.cin, L.cout, L.K, L.m_in, L.m_out)
                 for L in cell.net.layers]
         assert have == want
-        assert len(want) == convs[cfg["network"]]
+        assert len(want) == cfg["convs"]
         widths = cfg["cs"] if "cs" in cfg else [b[1] for b in cfg["blocks"]]
         assert {L.cout for L in cell.net.layers} == set(widths)
         assert set(cfg["reduced"]) == set(cfg["source_values"])

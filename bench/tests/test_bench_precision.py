@@ -18,10 +18,7 @@ ROOT = tiny.ROOT
 
 
 def _cell(config: dict, name: str = "sparse_resnet21") -> harness.Cell:
-    centry = {"name": name, "file": f"bench/configs/{name}.json"}
-    return harness.Cell(name=name, workload={}, config=config, chips=1,
-                        seed=3, seconds=0, trace=False, root=ROOT, t0=0.0,
-                        net=harness.reference_net(ROOT, centry, config))
+    return tiny.config_cell(f"bench/configs/{name}.json", config, seed=3)
 
 
 def _config(precision: str, name: str = "sparse_resnet21") -> dict:
@@ -34,7 +31,7 @@ def _serving_step_text(cell: harness.Cell) -> str:
     lowered (not compiled) under the process's current settings."""
     from repro.core.packing import BitLayout
     from repro.serve import compile_network
-    session = compile_network(common.program_net(cell),
+    session = compile_network(common.checked_program_net(cell),
                               BitLayout.for_extent(32, 28, 16))
     step = session._make_fn(0)
     return step.lower(session.params,

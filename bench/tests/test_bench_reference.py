@@ -8,14 +8,6 @@ import jax
 from bench import harness, reference, traffic
 from bench.tests import tiny
 
-ROOT = tiny.ROOT
-
-
-def _net(name):
-    centry = {"name": name, "file": f"bench/configs/{name}.json"}
-    cfg = harness.load_json(ROOT / centry["file"])
-    return cfg, harness.reference_net(ROOT, centry, cfg)
-
 
 def test_maps_match_the_brute_force_oracle():
     from repro.core.reference import downsample_reference, \
@@ -46,14 +38,12 @@ def test_reference_matches_the_session(config, extent, tol):
     from repro.core.sparse_tensor import SparseTensor
     from bench.modes import common
     from repro.serve import compile_network
-    cfg, net = _net(config)
+    cell = tiny.config_cell(f"bench/configs/{config}.json", seed=11)
+    net = cell.net
     scan = traffic.scan_batch(11, 1, "indoor", extent, 0.3)[0]
     feats = traffic.scan_features(scan, net.in_channels)
     params = reference.init_params(reference.seed_key(11), net)
-    cell = harness.Cell(name=config, workload={}, config=cfg, chips=1,
-                        seed=11, seconds=0, trace=False, root=ROOT, t0=0.0,
-                        net=net)
-    session = compile_network(common.program_net(cell),
+    session = compile_network(common.checked_program_net(cell),
                               BitLayout.for_extent(*extent), params=params)
     out = session(SparseTensor.from_point_cloud(scan.coords, feats,
                                                 session.layout))

@@ -164,21 +164,15 @@ def test_program_spans_are_read_from_the_trace(tmp_path):
 def _program_session(config: str):
     """The program's serving session for a configuration file, as the serve
     mode compiles it, with the reference's description of the network."""
-    from pathlib import Path
-    from bench import harness, traffic
+    from bench import traffic
     from bench.modes import common
     from bench.tests import tiny
     from repro.core.packing import BitLayout
     from repro.serve import compile_network
-    cfg = harness.load_json(tiny.ROOT / config)
-    centry = {"name": Path(config).stem, "file": config}
-    cell = harness.Cell(name=centry["name"], workload={}, config=cfg,
-                        chips=1, seed=0, seconds=0, trace=False,
-                        root=tiny.ROOT, t0=0.0,
-                        net=harness.reference_net(tiny.ROOT, centry, cfg))
+    cell = tiny.config_cell(config)
     tr = dict(tiny.SERVE["traffic"], pool=2)
     layout = BitLayout.for_extent(*tr["extent"], guard=traffic.GUARD)
-    session = compile_network(common.program_net(cell), layout)
+    session = compile_network(common.checked_program_net(cell), layout)
     return cell, tr, session
 
 

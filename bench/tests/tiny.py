@@ -43,10 +43,25 @@ def real_workloads() -> dict:
         for w in benchmark()["workloads"]}
 
 
+def config_cell(file: str, config: dict = None, **fields):
+    """A cell of the configuration file ``file`` (a path from the
+    repository's root; ``config`` in its place, where given) with its
+    reference file, as ``harness.open_cell`` makes one; ``fields`` set the
+    cell's others."""
+    from bench import harness
+    cfg = harness.load_json(ROOT / file) if config is None else config
+    centry = {"name": Path(file).stem, "file": file}
+    ref = harness.reference_file(ROOT, centry, cfg)
+    fields = dict(dict(name=centry["name"], workload={}, chips=1, seed=0,
+                       seconds=0, trace=False, root=ROOT, t0=0.0), **fields)
+    return harness.Cell(config=cfg, net=ref.net(cfg), hooks=ref, **fields)
+
+
 def make_root(tmp: Path, cells=None) -> Path:
     """A checkout under ``tmp`` with the repository's configurations, the
-    test configuration ``tiny_segnet`` and the metric readers, and tiny
-    cells (by default ``tiny.serve`` and ``tiny.train``). A tiny cell takes
+    test configurations (``bench/tests/*.json``) and the metric readers,
+    and tiny cells (by default ``tiny.serve`` and ``tiny.train``). A tiny
+    cell takes
     the limits and the optimizer of the real cell of its mode, where it
     names none (of the real cell of its configuration, where there is
     one), and reports the metrics of the real cells of its mode; a
@@ -58,11 +73,13 @@ def make_root(tmp: Path, cells=None) -> Path:
     (tmp / "bench" / "workloads").mkdir(parents=True)
     for d in ("configs", "metrics"):
         shutil.copytree(BENCH / d, tmp / "bench" / d)
-    for ext in ("json", "py"):
-        shutil.copy(TESTS / f"tiny_segnet.{ext}", tmp / "bench" / "configs")
-    bench["configs"].append({"name": "tiny_segnet", "source": "tests",
-                             "file": "bench/configs/tiny_segnet.json",
-                             "reduced": [], "why": "tests"})
+    for path in sorted(TESTS.glob("*.json")):
+        ref = json.loads(path.read_text())["reference"]
+        for f in (path, TESTS / ref):
+            shutil.copy(f, tmp / "bench" / "configs")
+        bench["configs"].append({"name": path.stem, "source": "tests",
+                                 "file": f"bench/configs/{path.name}",
+                                 "reduced": [], "why": "tests"})
     cells = cells or {"tiny.serve": SERVE, "tiny.train": TRAIN}
     entries = []
     for name, wl in cells.items():
